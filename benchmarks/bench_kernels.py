@@ -29,6 +29,11 @@ the jnp path also round-trips the touched (S×T) score area through HBM.
 
 Results land in ``artifacts/bench/kernels.json`` and a repo-level
 ``BENCH_kernels.json`` so the perf trajectory is tracked in-tree.
+
+This benchmark is not run on the chip and none of its times is a device
+time: the multi-device sweeps run in child processes pinned to the CPU
+(``JAX_PLATFORMS=cpu`` with forced host devices), so they never contend for
+an accelerator the parent may hold.  The on-chip benchmark replaces it.
 """
 from __future__ import annotations
 
@@ -447,10 +452,11 @@ import json, time
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.config import TrainConfig
+from repro.distributed.sharding import make_mesh
 from repro.kernels import dispatch, ref
 
 HBM_BW = %(hbm_bw)r
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 n_dev = mesh.devices.size
 backend = dispatch.KernelBackend("pallas", interpret=True, mesh=mesh,
                                  forced=True)
@@ -518,8 +524,9 @@ print("JSON_ROWS " + json.dumps(rows))
 def _sharded_step_rows():
     """Host-8-device shard-mapped sweep, run in a subprocess so this process
     keeps its single-device view (same pattern as tests/test_distributed.py).
-    On TPU the in-process mesh is the real benchmark; this sweep tracks the
-    shard_map dispatch overhead/parity trend on the CPU emulation."""
+    The child is pinned to the CPU and never runs on the chip; this sweep
+    tracks the shard_map dispatch overhead/parity trend on the CPU
+    emulation."""
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
@@ -546,15 +553,14 @@ _REDUCE_BENCH = """
 import json, time
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.config import GradESConfig, ModelConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
 from repro.core.partition import (fully_frozen_types, gradient_reduce_plan,
                                   plan_row_masks, segment_plan,
                                   trainable_mask)
-from repro.distributed import (compress_with_feedback, reduce_gradients,
-                               reduce_plan_bytes)
+from repro.distributed import (compress_with_feedback, make_mesh,
+                               reduce_gradients, reduce_plan_bytes)
 from repro.launch.roofline import analyze_hlo
 from repro.optim.optimizer import align_packed_tree
 from repro.train.state import init_train_state
@@ -571,7 +577,7 @@ tcfg = TrainConfig(seq_len=8, global_batch=8, steps=8, lr=1e-3,
 params = init_train_state(jax.random.PRNGKey(0), cfg, tcfg).params
 spec = build_monitor_spec(params)
 L = cfg.n_layers
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 def timed(fn, *args, reps=10):
     # min over many reps: CPU-emulated collectives jitter ~10% run-to-run on
@@ -628,9 +634,9 @@ for mode in ("tier1_drop", "rowsliced"):
     grads = jax.tree.map(zero_frozen, raw, trainable)
 
     def reduce_with(rp):
-        return jax.jit(shard_map(
-            lambda g: reduce_gradients(g, ("data",), rp), mesh,
-            in_specs=(P(),), out_specs=P(), check_rep=False))
+        return jax.jit(jax.shard_map(
+            lambda g: reduce_gradients(g, ("data",), rp), mesh=mesh,
+            in_specs=(P(),), out_specs=P(), check_vma=False))
 
     planned, full = reduce_with(rplan), reduce_with(None)
     hlo = planned.lower(grads).compile().as_text()
@@ -700,7 +706,8 @@ print("JSON_ROWS " + json.dumps(rows))
 
 def _reduce_rows():
     """Freeze-aware explicit-reduce sweep on 8 host CPU devices, run in a
-    subprocess so this process keeps its single-device view.  Measured HLO
+    subprocess (pinned to the CPU, never on the chip) so this process keeps
+    its single-device view.  Measured HLO
     collective bytes and reduce wall time must strictly decrease with the
     frozen fraction; every swept fraction must be bit-identical to the
     full-tree reduce (frozen grads are exactly zero)."""

@@ -10,12 +10,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 
+from repro.launch.cache import enable_compile_cache
 import repro.configs as configs
 from repro.config import GradESConfig, LoRAConfig, TrainConfig
 from repro.train.loop import Trainer
 
 
 def main():
+    enable_compile_cache()
     cfg = configs.reduced("yi-9b")
     tcfg = TrainConfig(
         seq_len=32, global_batch=8, steps=250, lr=1e-2,

@@ -9,12 +9,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
 
+from repro.launch.cache import enable_compile_cache
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.train.loop import Trainer
 
 
 def main():
+    enable_compile_cache()
     cfg = configs.reduced("qwen3-0.6b")
     tcfg = TrainConfig(
         seq_len=32, global_batch=8, steps=300, lr=3e-3,

@@ -30,6 +30,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 
+from repro.launch.cache import enable_compile_cache
 import repro.configs as configs
 from repro.models import model
 
@@ -70,6 +71,7 @@ def generate(params, cfg, prompts, max_new: int, temperature: float = 0.0,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="hymba-1.5b")
     ap.add_argument("--batch", type=int, default=4)

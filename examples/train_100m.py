@@ -13,6 +13,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.config import GradESConfig, ModelConfig, TrainConfig
 from repro.data.pipeline import PackedFileDataset, SyntheticTask
 from repro.train.loop import Trainer
@@ -32,6 +33,7 @@ PRESETS = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=list(PRESETS), default="small")
     ap.add_argument("--steps", type=int, default=0)

@@ -271,8 +271,8 @@ class TrainConfig:
     # distributed/reduce.py).  "auto" computes grads inside a shard_map that
     # is manual over the DP mesh axes and psums per-leaf under the boundary
     # ReducePlan — frozen leaves/rows drop out of the collective entirely —
-    # whenever the active mesh is purely data-parallel; tensor-parallel or
-    # sharded-Pallas configs keep the implicit GSPMD reduce.  "explicit"
+    # whenever the active mesh is purely data-parallel; tensor-parallel
+    # configs keep the implicit GSPMD reduce.  "explicit"
     # raises instead of falling back; "implicit" never engages.
     reduce_mode: str = "auto"            # "auto" | "explicit" | "implicit"
     # checkpointing.  NOTE: with GradES static repartition on, the Tier-1/1.5

@@ -25,6 +25,7 @@ from repro.distributed.sharding import (  # noqa: F401
     active_rules,
     logical_constraint,
     logical_to_spec,
+    make_mesh,
     named_sharding,
     param_partition_specs,
     suspend_mesh,

@@ -17,10 +17,12 @@ Eligibility (:func:`explicit_reduce_axes`): the explicit path engages when the
 active mesh is purely data-parallel — every >1-sized axis is a DP axis
 (``data`` / ``pod``) — because the loss body runs *manual* on all mesh axes
 (tensor-parallel configs keep the implicit GSPMD reduce, where the model-axis
-sharding must stay under the compiler).  Sharded-Pallas backends are also
-excluded: their kernels are themselves shard_map wrappers and cannot nest
-inside the manual body.  ``TrainConfig.reduce_mode`` selects ``auto`` (engage
-when eligible), ``explicit`` (raise when ineligible), or ``implicit`` (never).
+sharding must stay under the compiler).  The Pallas kernels take part: the
+flash kernels run unwrapped on each shard inside the manual body, and since
+the reduced gradients, parameters and moments are replicated, the GradES
+kernels run shard_map-wrapped over replicated specs (``train/step.py``).
+``TrainConfig.reduce_mode`` selects ``auto`` (engage when eligible),
+``explicit`` (raise when ineligible), or ``implicit`` (never).
 """
 from __future__ import annotations
 
@@ -36,7 +38,7 @@ from repro.core.partition import ReducePlan
 DP_AXES = ("pod", "data")
 
 
-def explicit_reduce_axes(mesh, tcfg, backend=None) -> Optional[Tuple[str, ...]]:
+def explicit_reduce_axes(mesh, tcfg) -> Optional[Tuple[str, ...]]:
     """The DP axis names the explicit reduce psums over, or None to keep the
     implicit GSPMD reduce.  See the module docstring for the eligibility
     rules; ``reduce_mode="explicit"`` raises instead of silently falling
@@ -55,8 +57,6 @@ def explicit_reduce_axes(mesh, tcfg, backend=None) -> Optional[Tuple[str, ...]]:
         blockers.append("mesh has a >1-sized non-DP axis (tensor parallel)")
     if not axes:
         blockers.append("mesh has no >1-sized data-parallel axis")
-    if backend is not None and backend.use_pallas and backend.sharded:
-        blockers.append("sharded-Pallas kernels cannot nest in the manual body")
     ndev = 1
     for a in axes:
         ndev *= sizes[a]

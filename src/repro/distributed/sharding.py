@@ -8,6 +8,11 @@ serves every architecture.
 
 ``use_mesh(mesh, rules)`` installs a process-global context; ``logical_constraint``
 is a no-op outside it, so single-device unit tests run the exact same model code.
+
+Every mesh axis is ``Auto`` (GSPMD propagates shardings from the constraints).
+``jax.make_mesh`` now defaults to explicit axes, under which JAX refuses those
+constraints and the embedding gather, so meshes come from :func:`make_mesh`
+and ``use_mesh`` refuses any other kind.
 """
 from __future__ import annotations
 
@@ -17,7 +22,7 @@ from dataclasses import dataclass, field
 from typing import Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Logical = Union[str, None, Tuple[str, ...]]
 
@@ -101,8 +106,22 @@ class _Ctx(threading.local):
 _ctx = _Ctx()
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """A device mesh with every axis ``Auto`` (see the module docstring);
+    ``devices`` defaults to all of ``jax.devices()``."""
+    devices = jax.devices() if devices is None else devices
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh, rules: Optional[ShardingRules] = None):
+    if any(t != AxisType.Auto for t in mesh.axis_types):
+        raise ValueError(
+            f"use_mesh needs Auto mesh axes, got {mesh.axis_types}: build "
+            f"the mesh with repro.distributed.sharding.make_mesh")
     prev = (_ctx.mesh, _ctx.rules)
     _ctx.mesh = mesh
     _ctx.rules = rules or (_ctx.rules or DEFAULT_RULES)

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.masking import NEG_INF
 
 
@@ -87,8 +88,8 @@ def _decode_kernel(table_ref, vc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(slot0 < vc)  # pages fully past the valid tail contribute nothing
     def _page():
         q = q_ref[0, 0].astype(jnp.float32)               # (Gp, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (ps, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)                  # (ps, hd)
+        v = v_ref[0].astype(jnp.float32)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         cols = slot0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         sc = jnp.where(cols < vc, sc, NEG_INF)            # ragged page tail
@@ -104,7 +105,7 @@ def _decode_kernel(table_ref, vc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = (m_ref[...] + jnp.log(l)).reshape(lse_ref.shape[3:])
+        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
 
 
 def combine_splits(o_split, lse_split):
@@ -128,13 +129,20 @@ def default_pages_per_split(page_size: int, n_pages_per_seq: int,
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, valid_count, *,
-                           pages_per_split: int = 0, interpret: bool = True):
+                           pages_per_split: int = 0, interpret=None):
     """Split-KV decode attention over a paged pool.
 
     q: (B, 1, KV, G, hd); k_pages/v_pages: (N, page_size, KV, hd);
     page_table: (B, P) int32 physical page ids; valid_count: (B,) int32 valid
     slots (<= P * page_size).  Returns (B, 1, KV, G, hd).  Matches
     :func:`paged_decode_ref` (the gathered dense softmax) to flash tolerance.
+    ``interpret`` None = derived from the backend
+    (:func:`repro.kernels.resolve_interpret`).
+
+    The kernel sees the pool as ``(N, page_size, KV·hd)`` (a free reshape)
+    and fetches one ``(page_size, hd)`` head slab per grid step, so every
+    block obeys the TPU tiling rule when ``hd`` is a multiple of 128 or
+    ``KV == 1`` (:func:`repro.kernels.dispatch.paged_decode_restriction`).
     """
     B, S, KV, G, hd = q.shape
     assert S == 1, q.shape
@@ -150,6 +158,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, valid_count, *,
     if Gp != G:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
 
+    kp = k_pages.reshape(N, ps, KV * hd)
+    vp = v_pages.reshape(N, ps, KV * hd)
     grid = (B, KV, n_splits, spp)
     kernel = functools.partial(_decode_kernel, ps=ps, spp=spp,
                                scale=hd ** -0.5)
@@ -161,18 +171,18 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, valid_count, *,
             in_specs=[
                 pl.BlockSpec((1, 1, Gp, hd),
                              lambda b, h, s, j, pt, vc: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, 1, hd),
+                pl.BlockSpec((1, ps, hd),
                              lambda b, h, s, j, pt, vc:
-                             (pt[b, s * spp + j], 0, h, 0)),
-                pl.BlockSpec((1, ps, 1, hd),
+                             (pt[b, s * spp + j], 0, h)),
+                pl.BlockSpec((1, ps, hd),
                              lambda b, h, s, j, pt, vc:
-                             (pt[b, s * spp + j], 0, h, 0)),
+                             (pt[b, s * spp + j], 0, h)),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, 1, Gp, hd),
                              lambda b, h, s, j, pt, vc: (b, h, s, 0, 0)),
-                pl.BlockSpec((1, 1, 1, Gp),
-                             lambda b, h, s, j, pt, vc: (b, h, s, 0)),
+                pl.BlockSpec((1, 1, 1, Gp, 1),
+                             lambda b, h, s, j, pt, vc: (b, h, s, 0, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((Gp, hd), jnp.float32),
@@ -182,13 +192,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, valid_count, *,
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, n_splits, Gp, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, n_splits, Gp), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, n_splits, Gp, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="paged_decode",
     )(page_table.astype(jnp.int32), valid_count.astype(jnp.int32),
-      qr, k_pages, v_pages)
+      qr, kp, vp)
 
-    o = combine_splits(o_split, lse_split)[:, :, :G]       # (B, KV, G, hd)
+    o = combine_splits(o_split, lse_split[..., 0])[:, :, :G]  # (B, KV, G, hd)
     return o[:, None].astype(q.dtype)
 
 

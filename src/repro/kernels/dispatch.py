@@ -24,7 +24,7 @@ non-stacked, unmonitored) falls back to jnp within the same step.
 Sharded dispatch
 ----------------
 ``pallas_call`` has no GSPMD partitioning rule, so under a multi-device mesh
-every fused call is wrapped in :func:`jax.experimental.shard_map.shard_map`
+every fused call is wrapped in :func:`jax.shard_map`
 over the leaf's :class:`~jax.sharding.PartitionSpec` (derived from the model's
 logical-axis tree — ``distributed.sharding.param_partition_specs``):
 
@@ -52,7 +52,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.sharding import (ATTN_KV_AXES, ATTN_MASK_AXES,
@@ -275,10 +274,10 @@ def fused_grades_norm(g, prev, gran: int, backend: KernelBackend,
             norm = jax.lax.psum(norm, trailing_axes)
         return norm, new_prev
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(*parts), P(*parts), P()),
-                     out_specs=(P(*parts[:gran]), P(*parts)),
-                     check_rep=False)(g, prev, flags)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(*parts), P(*parts), P()),
+                         out_specs=(P(*parts[:gran]), P(*parts)),
+                         check_vma=False)(g, prev, flags)
 
 
 def _local_masked_update(p, g, m, v, flags, lr, count, tcfg,
@@ -331,10 +330,10 @@ def fused_masked_update(p, g, m, v, flags, lr, count, tcfg,
                                              None, tcfg, backend)
             return p3, m3
 
-        p3, m3 = shard_map(local_sgd, mesh=mesh,
-                           in_specs=(tsp, tsp, tsp, rep, rep),
-                           out_specs=(tsp, tsp),
-                           check_rep=False)(p, g, m, flags, lr)
+        p3, m3 = jax.shard_map(local_sgd, mesh=mesh,
+                               in_specs=(tsp, tsp, tsp, rep, rep),
+                               out_specs=(tsp, tsp),
+                               check_vma=False)(p, g, m, flags, lr)
         return p3, m3, v
 
     def local(p_l, g_l, m_l, v_l, flags_full, lr_l, count_l):
@@ -342,10 +341,10 @@ def fused_masked_update(p, g, m, v, flags, lr, count, tcfg,
         return _local_masked_update(p_l, g_l, m_l, v_l, fl, lr_l, count_l,
                                     tcfg, backend)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(tsp, tsp, tsp, tsp, rep, rep, rep),
-                     out_specs=(tsp, tsp, tsp),
-                     check_rep=False)(p, g, m, v, flags, lr, count)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(tsp, tsp, tsp, tsp, rep, rep, rep),
+                         out_specs=(tsp, tsp, tsp),
+                         check_vma=False)(p, g, m, v, flags, lr, count)
 
 
 # ---------------------------------------------------------------------------
@@ -433,22 +432,27 @@ def fused_flash_attention(q, k, v, *, causal: bool, window: int = 0,
         def local(q_l, k_l, v_l):
             return flash_attention(q_l, k_l, v_l, **kw)
 
-        return shard_map(local, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
-                         out_specs=qspec, check_rep=False)(q, k, v)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(qspec, kvspec, kvspec),
+                             out_specs=qspec, check_vma=False)(q, k, v)
     mspec = logical_to_spec(ATTN_MASK_AXES, shape=kv_valid.shape, mesh=mesh,
                             rules=rules)
 
     def local(q_l, k_l, v_l, m_l):
         return flash_attention(q_l, k_l, v_l, kv_valid=m_l, **kw)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(qspec, kvspec, kvspec, mspec),
-                     out_specs=qspec, check_rep=False)(q, k, v, kv_valid)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(qspec, kvspec, kvspec, mspec),
+                         out_specs=qspec, check_vma=False)(q, k, v, kv_valid)
 
 
-def paged_decode_restriction(q_shape, pages_shape, dtype) -> Optional[str]:
+def paged_decode_restriction(q_shape, pages_shape, dtype, *,
+                             compiled: bool = False) -> Optional[str]:
     """Why the split-KV kernel cannot take this paged decode call — None when
-    it can.  Shape-static, so routing never recompiles the decode block."""
+    it can.  Shape-static, so routing never recompiles the decode block.
+    ``compiled`` adds the TPU lowering's rule: the kernel fetches one KV
+    head of a page as a ``(page_size, hd)`` lane slab, which needs ``hd`` to
+    be a multiple of 128 unless the pool holds a single KV head."""
     if len(q_shape) != 5 or len(pages_shape) != 4:
         return (f"unexpected layout q{tuple(q_shape)} / pages"
                 f"{tuple(pages_shape)} (want (B,1,KV,G,hd) / (N,ps,KV,hd))")
@@ -464,6 +468,9 @@ def paged_decode_restriction(q_shape, pages_shape, dtype) -> Optional[str]:
         return f"head_dim {hd} not a multiple of the 8-sublane layout"
     if ps % 8 != 0:
         return f"page_size {ps} not a multiple of the 8-sublane layout"
+    if compiled and pages_shape[2] > 1 and hd % 128 != 0:
+        return (f"head_dim {hd} not a multiple of the 128-lane tile, so the "
+                f"compiled kernel cannot fetch one KV head of a page")
     return None
 
 
@@ -472,7 +479,8 @@ def paged_decode_ok(q, k_pages, backend: KernelBackend) -> bool:
     reason when pallas was forced but the call falls back to the jnp gather."""
     if not backend.use_pallas:
         return False
-    reason = paged_decode_restriction(q.shape, k_pages.shape, q.dtype)
+    reason = paged_decode_restriction(q.shape, k_pages.shape, q.dtype,
+                                      compiled=not backend.interpret)
     if reason is not None:
         _warn_forced_attention_fallback(backend, reason)
         return False
@@ -506,10 +514,10 @@ def fused_paged_decode(q, k_pages, v_pages, page_table, valid_count, *,
     def local(q_l, k_l, v_l, t_l, c_l):
         return paged_decode_attention(q_l, k_l, v_l, t_l, c_l, **kw)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(qspec, pspec, pspec, tspec, vspec),
-                     out_specs=qspec, check_rep=False)(
-                         q, k_pages, v_pages, page_table, valid_count)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(qspec, pspec, pspec, tspec, vspec),
+                         out_specs=qspec, check_vma=False)(
+                             q, k_pages, v_pages, page_table, valid_count)
 
 
 def moments_fusable(m, v, p, optimizer: str) -> bool:

@@ -24,8 +24,12 @@ covering everything ``attention()`` actually uses:
 Grid (fwd / dq): (B, KV, R/bq, T/bk) with R = G·S_padded; the innermost KV
 tile loop is sequential so running (m, l, acc) live in VMEM scratch.  Tiles
 are (bq, hd)/(bk, hd) slabs — multiples of the 8×128 VREG layout for the
-default 256×256 blocks.  Causal/window tiles that cannot contribute are
-predicated off with ``pl.when`` on the tile's row offset.
+default 256×256 blocks.  Every block's last two dims obey the TPU tiling rule
+(each divisible by 8 / 128 or equal to the array's own): the kv-valid mask is
+laid out ``(B, 1, Tp)`` and read as ``(1, bk)`` rows, and the per-row
+logsumexp / ``D`` residuals are ``(B, KV, R, 1)`` columns read as ``(bq, 1)``.
+Causal/window tiles that cannot contribute are predicated off with
+``pl.when`` on the tile's row offset.
 """
 from __future__ import annotations
 
@@ -36,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.masking import (NEG_INF, band_live, rows_alive,
                                    zero_dead_rows)
 
@@ -89,6 +94,14 @@ def _rows_to_kv(kt, T: int):
     return kt[:, :, :T].transpose(0, 2, 1, 3)
 
 
+def _mask_rows(mask, Tp: int):
+    """(B, T) kv-valid gate -> (B, 1, Tp), padded columns masked."""
+    T = mask.shape[1]
+    if Tp != T:
+        mask = jnp.pad(mask, ((0, 0), (0, Tp - T)))
+    return mask[:, None, :]
+
+
 # ---------------------------------------------------------------------------
 # In-kernel masking (shared by forward and both backward kernels)
 # ---------------------------------------------------------------------------
@@ -103,10 +116,10 @@ def _tile_live(off, kj, *, bq: int, bk: int, causal: bool, window: int):
 def _mask_tile(s, off, col0, mask_row, *, causal: bool, window: int):
     """Apply kv-valid/padding + causal + window masks to one (bq, bk) tile.
     ``off`` is the sequence position of the tile's first row, ``col0`` of its
-    first column; ``mask_row (bk,)`` is the f32 0/1 kv-valid slice."""
+    first column; ``mask_row (1, bk)`` is the f32 0/1 kv-valid slice."""
     rows = off + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    ok = mask_row[None, :] > 0.0
+    ok = mask_row > 0.0
     if causal:
         ok = jnp.logical_and(ok, cols <= rows)
     if window:
@@ -146,14 +159,15 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0, 0]).astype(jnp.float32)
+            p.astype(v_ref.dtype), v_ref[0, 0],
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(kj == nk - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).reshape(lse_ref.shape[2:])
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def _forward(q, k, v, mask, *, causal: bool, window: int, block_q: int,
@@ -166,7 +180,7 @@ def _forward(q, k, v, mask, *, causal: bool, window: int, block_q: int,
     qr = _q_to_rows(q, Sp)
     kr = _kv_to_rows(k, Tp)
     vr = _kv_to_rows(v, Tp)
-    mp = jnp.pad(mask, ((0, 0), (0, Tp - T))) if Tp != T else mask
+    mp = _mask_rows(mask, Tp)
     grid = (B, KV, R // bq, Tp // bk)
     kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, Sp=Sp,
                                causal=causal, window=window,
@@ -175,18 +189,18 @@ def _forward(q, k, v, mask, *, causal: bool, window: int, block_q: int,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j)),
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, KV, R), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, R, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, hd), jnp.float32),
@@ -194,6 +208,7 @@ def _forward(q, k, v, mask, *, causal: bool, window: int, block_q: int,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(mp, qr, kr, vr)
     return _rows_to_q(o_rows, S, G), (o_rows, lse)
 
@@ -224,10 +239,9 @@ def _dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         s = _mask_tile(s, off, kj * bk, mask_ref[0], causal=causal,
                        window=window)
-        lse = lse_ref[0, 0].reshape(bq, 1)
-        p = jnp.exp(s - lse)                                    # (bq, bk)
+        p = jnp.exp(s - lse_ref[0, 0])                          # (bq, bk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta_ref[0, 0].reshape(bq, 1))
+        ds = p * (dp - delta_ref[0, 0])
         acc_ref[...] += jax.lax.dot(ds, k) * scale
 
     @pl.when(kj == nk - 1)
@@ -258,10 +272,10 @@ def _dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         s = _mask_tile(s, off, kj * bk, mask_ref[0], causal=causal,
                        window=window)
-        p = jnp.exp(s - lse_ref[0, 0].reshape(bq, 1))           # (bq, bk)
+        p = jnp.exp(s - lse_ref[0, 0])                          # (bq, bk)
         dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta_ref[0, 0].reshape(bq, 1))
+        ds = p * (dp - delta_ref[0, 0])
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ()))) * scale
 
@@ -281,17 +295,17 @@ def _backward(q, k, v, mask, o_rows, lse, do, *, causal: bool, window: int,
     kr = _kv_to_rows(k, Tp)
     vr = _kv_to_rows(v, Tp)
     dor = _q_to_rows(do, Sp)  # padded rows carry zero cotangents
-    mp = jnp.pad(mask, ((0, 0), (0, Tp - T))) if Tp != T else mask
+    mp = _mask_rows(mask, Tp)
     # D_i = sum_d dO_i·O_i — one elementwise pass, shared by both kernels.
     delta = jnp.sum(dor.astype(jnp.float32) * o_rows.astype(jnp.float32),
-                    axis=-1)
+                    axis=-1, keepdims=True)
     kw = dict(bq=bq, bk=bk, Sp=Sp, causal=causal, window=window,
               scale=hd ** -0.5)
 
-    mask_spec = pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j))
+    mask_spec = pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j))
     q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
+    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
     dqr = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
         grid=(B, KV, R // bq, Tp // bk),
@@ -301,14 +315,15 @@ def _backward(q, k, v, mask, o_rows, lse, do, *, causal: bool, window: int,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(mp, qr, kr, vr, dor, lse, delta)
 
     # Transposed grid: the sequential inner loop walks ALL G·Sp query rows of
     # this KV head, accumulating the GQA group reduction into dk/dv.
-    t_mask = pl.BlockSpec((1, bk), lambda b, h, j, i: (b, j))
+    t_mask = pl.BlockSpec((1, 1, bk), lambda b, h, j, i: (b, 0, j))
     t_q = pl.BlockSpec((1, 1, bq, hd), lambda b, h, j, i: (b, h, i, 0))
     t_kv = pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, i: (b, h, j, 0))
-    t_row = pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i))
+    t_row = pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0))
     dkr, dvr = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
         grid=(B, KV, Tp // bk, R // bq),
@@ -319,6 +334,7 @@ def _backward(q, k, v, mask, o_rows, lse, do, *, causal: bool, window: int,
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(mp, qr, kr, vr, dor, lse, delta)
 
     dq = _rows_to_q(dqr, S, G).astype(q.dtype)
@@ -359,13 +375,15 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     kv_valid=None, block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True):
+                    interpret=None):
     """Flash attention in the model layout, differentiable end to end.
 
     q: (B, S, KV, G, hd); k, v: (B, T, KV, hd); kv_valid: optional (B, T)
     bool/0-1 validity mask.  Returns (B, S, KV, G, hd).  Matches
     ``models.attention.full_attention`` (and its gradients) for causal,
     windowed, GQA, and padded-length cases; S/T need not be block multiples.
+    ``interpret`` None = derived from the backend
+    (:func:`repro.kernels.resolve_interpret`).
     """
     B, S, KV, G, hd = q.shape
     T = k.shape[1]
@@ -374,7 +392,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     mask = (jnp.ones((B, T), jnp.float32) if kv_valid is None
             else kv_valid.astype(jnp.float32))
     out = _flash(bool(causal), int(window), int(block_q), int(block_k),
-                 bool(interpret), q, k, v, mask)
+                 resolve_interpret(interpret), q, k, v, mask)
     # Rows with no visible valid key get exactly zero output/grads on every
     # backend (see masking.rows_alive) — in-kernel they'd be backend-dependent
     # garbage (uniform over visited tiles vs. uniform over all T columns).

@@ -13,14 +13,16 @@ partial L1 accumulated in VMEM across the N-tile loop.
 
 Freezing is permanent (GradES monotonicity), so a frozen layer's monitor value
 can never un-freeze it — its 2 reads + 1 ``prev`` write-back are pure waste.
-The flags ride in a full-array (ANY/SMEM-like) spec exactly like
-``masked_adamw``'s, so the predicate is known before the tile DMAs are issued
-and a frozen layer costs one flag load; ``input_output_aliases`` pins ``prev'``
+The flags ride whole in SMEM exactly like ``masked_adamw``'s, so the predicate
+is a scalar load and a frozen layer costs no vector work; ``input_output_aliases`` pins ``prev'``
 onto ``prev`` so the frozen copy-through is a no-op store on hardware (the
 explicit copy is required for interpret-mode correctness).
 
-Grid: (L, M/bm, N/bn), sequential on TPU, so the (1,1) accumulator block for layer
+Grid: (L, M/bm, N/bn), sequential on TPU, so the accumulator block for layer
 ``l`` is initialized at the first (i,j) tile and accumulated in place after.
+The per-layer norm is kept as an ``(L, 1, 128)`` lane row (every lane holds the
+same sum): a ``(1, 1, 128)`` block satisfies the TPU tiling rule, which a
+``(1, 1)`` block of an ``(L, 1)`` array does not.
 Block shapes default to (256, 512) — 512 KiB of bf16 per input tile, comfortably
 inside the ~16 MiB VMEM budget with double buffering, and both dims are multiples
 of the 8×128 VREG lane layout.
@@ -32,11 +34,15 @@ psums partials over the mesh axes that shard trailing dims to recover Eq. 1.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
+
+#: lane width of the per-layer norm row (one TPU vreg row)
+_LANES = 128
 
 
 def _kernel(flags_ref, g_ref, prev_ref, norm_ref, newprev_ref):
@@ -47,13 +53,13 @@ def _kernel(flags_ref, g_ref, prev_ref, norm_ref, newprev_ref):
 
     @pl.when((i == 0) & (j == 0))
     def _init():
-        norm_ref[0, 0] = 0.0
+        norm_ref[...] = jnp.zeros_like(norm_ref)
 
     @pl.when(live)
     def _update():
         g = g_ref[0]
         delta = (g.astype(jnp.float32) - prev_ref[0].astype(jnp.float32))
-        norm_ref[0, 0] += jnp.sum(jnp.abs(delta))
+        norm_ref[...] += jnp.sum(jnp.abs(delta))
         newprev_ref[0] = g.astype(newprev_ref.dtype)
 
     @pl.when(jnp.logical_not(live))
@@ -64,9 +70,10 @@ def _kernel(flags_ref, g_ref, prev_ref, norm_ref, newprev_ref):
 
 
 def grades_norm_kernel(g, prev, frozen=None, *, block_m: int = 256,
-                       block_n: int = 512, interpret: bool = True):
+                       block_n: int = 512, interpret=None):
     """g, prev: (L, M, N); frozen: (L,) bool/int or None (all live)
-    -> (norm (L,), new_prev (L, M, N))."""
+    -> (norm (L,), new_prev (L, M, N)).  ``interpret`` None = derived from
+    the backend (:func:`repro.kernels.resolve_interpret`)."""
     L, M, N = g.shape
     flags = (jnp.zeros((L,), jnp.int32) if frozen is None
              else frozen.astype(jnp.int32))
@@ -78,19 +85,20 @@ def grades_norm_kernel(g, prev, frozen=None, *, block_m: int = 256,
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # flags: full, SMEM-like
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # flags: whole, scalars
             pl.BlockSpec((1, bm, bn), lambda l, i, j: (l, i, j)),
             pl.BlockSpec((1, bm, bn), lambda l, i, j: (l, i, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda l, i, j: (l, 0)),
+            pl.BlockSpec((1, 1, _LANES), lambda l, i, j: (l, 0, 0)),
             pl.BlockSpec((1, bm, bn), lambda l, i, j: (l, i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((L, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L, 1, _LANES), jnp.float32),
             jax.ShapeDtypeStruct(g.shape, prev.dtype),
         ],
         input_output_aliases={2: 1},
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="grades_norm",
     )(flags, g, prev)
-    return norm[:, 0], new_prev
+    return norm[:, 0, 0], new_prev

@@ -14,8 +14,9 @@ frozen-branch copy-through is a true no-op write on TPU (the explicit copies
 below are required for interpret-mode correctness and are elided under
 aliasing on hardware).
 
-Grid (L, M/bm, N/bn); flags and hyper use full-array (ANY) specs so the
-predicate is known before the tile's DMAs are issued.
+Grid (L, M/bm, N/bn); flags and hyper ride whole in SMEM, so the predicate
+and the hyperparameters are scalar loads (a TPU kernel may load only from
+VMEM or SMEM references).
 
 The update is elementwise, so under a sharded mesh the kernel body runs
 unchanged per shard (shard_map in ``kernels/dispatch.py``); ``frozen`` then
@@ -27,6 +28,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 #: layout of the dynamic hyper operand (f32 vector)
 HYPER_LEN = 7  # [lr, b1, b2, eps, weight_decay, 1-b1**t, 1-b2**t]
@@ -81,7 +85,7 @@ def _sgd_body(flags_ref, hyper_ref, p_ref, g_ref, m_ref, p_out, m_out):
 
 
 def _blocked(body, p, operands, n_state: int, block_m: int, block_n: int,
-             interpret: bool):
+             interpret, name: str):
     """Shared pallas_call plumbing: (flags, hyper, p, g, state...) ->
     (p', state'...); the mutable operands alias their outputs."""
     L, M, N = p.shape
@@ -97,31 +101,32 @@ def _blocked(body, p, operands, n_state: int, block_m: int, block_n: int,
         grid_spec=pl.GridSpec(
             grid=grid,
             in_specs=[
-                pl.BlockSpec(memory_space=pl.ANY),  # flags: full, SMEM-like
-                pl.BlockSpec(memory_space=pl.ANY),  # hyper
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # flags: whole
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # hyper
             ] + [spec] * n_tensor,
             out_specs=[spec] * (1 + n_state),
         ),
         out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype) for o in outs],
         input_output_aliases={inp: out for out, inp in enumerate(mutable)},
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name=name,
     )(*operands)
 
 
 def masked_adamw_kernel(p, g, m, v, frozen, hyper, *, block_m: int = 256,
-                        block_n: int = 512, interpret: bool = True):
+                        block_n: int = 512, interpret=None):
     """p,g,m,v: (L, M, N); frozen: (L,) bool/int; hyper: (7,) f32 dynamic
     vector ``[lr, b1, b2, eps, wd, 1-b1**t, 1-b2**t]``. Returns (p', m', v')."""
     flags = frozen.astype(jnp.int32)
     hyper = jnp.asarray(hyper, jnp.float32)
     return _blocked(_adamw_body, p, (flags, hyper, p, g, m, v), 2,
-                    block_m, block_n, interpret)
+                    block_m, block_n, interpret, "masked_adamw")
 
 
 def masked_sgd_kernel(p, g, m, frozen, hyper, *, block_m: int = 256,
-                      block_n: int = 512, interpret: bool = True):
+                      block_n: int = 512, interpret=None):
     """SGD-momentum variant: p,g,m: (L, M, N). Returns (p', m')."""
     flags = frozen.astype(jnp.int32)
     hyper = jnp.asarray(hyper, jnp.float32)
     return _blocked(_sgd_body, p, (flags, hyper, p, g, m), 1,
-                    block_m, block_n, interpret)
+                    block_m, block_n, interpret, "masked_sgd")

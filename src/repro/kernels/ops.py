@@ -7,7 +7,9 @@ bias-correction terms derived from it — are *dynamic* operands packed into the
 kernels' ``hyper`` vector: a 10-step cosine-schedule run compiles each
 (shape, dtype) bucket exactly once (regression-tested in
 ``tests/test_dispatch.py``).  Only true structure (shapes, interpret mode,
-moment betas baked into nothing) stays static.
+moment betas baked into nothing) stays static.  ``interpret=None`` derives the
+mode from the backend (compiled on TPU, interpreted elsewhere —
+:func:`repro.kernels.resolve_interpret`).
 
 Under a sharded backend these wrappers are invoked *per shard* from inside the
 dispatch layer's ``shard_map`` (``kernels/dispatch.py``): they only ever see
@@ -46,7 +48,7 @@ def _blocks(shape3, block_m, block_n):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_m", "block_n"))
-def grades_norm(g, prev, frozen=None, *, interpret: bool = True,
+def grades_norm(g, prev, frozen=None, *, interpret=None,
                 block_m: int = 256, block_n: int = 512):
     """Fused GradES monitor: (norm (L,), new_prev) for stacked (L, ...) grads.
 
@@ -79,7 +81,7 @@ def _adamw_hyper(lr, count, b1, b2, eps, weight_decay):
 @functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "weight_decay",
                                              "interpret"))
 def masked_adamw(p, g, m, v, frozen, lr, count, *, b1=0.9, b2=0.95, eps=1e-8,
-                 weight_decay=0.0, interpret: bool = True):
+                 weight_decay=0.0, interpret=None):
     """Frozen-gated AdamW on a stacked (L, ...) leaf.  ``lr`` and ``count``
     are dynamic (no recompile under a schedule)."""
     shape = p.shape
@@ -95,7 +97,7 @@ def masked_adamw(p, g, m, v, frozen, lr, count, *, b1=0.9, b2=0.95, eps=1e-8,
 
 @functools.partial(jax.jit, static_argnames=("b1", "weight_decay", "interpret"))
 def masked_sgd(p, g, m, frozen, lr, *, b1=0.9, weight_decay=0.0,
-               interpret: bool = True):
+               interpret=None):
     """Frozen-gated SGD-momentum on a stacked (L, ...) leaf (dynamic ``lr``)."""
     shape = p.shape
     c3 = _canon3

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(xp_ref, r_ref, h0_ref, c0_ref, n0_ref, m0_ref,
             hseq_ref, hT_ref, cT_ref, nT_ref, mT_ref,
@@ -88,8 +90,10 @@ def _kernel(xp_ref, r_ref, h0_ref, c0_ref, n0_ref, m0_ref,
 
 
 def slstm_kernel(x_proj, r, h0, c0, n0, m0, *, n_heads: int, chunk: int = 128,
-                 block_b: int = 0, interpret: bool = True):
+                 block_b: int = 0, interpret=None):
     """x_proj: (B, T, 4D); r: (4, H, hd, hd); states (B, D) f32.
+    ``interpret`` None = derived from the backend
+    (:func:`repro.kernels.resolve_interpret`).
 
     Returns (h_seq (B, T, D), h_T, c_T, n_T, m_T)."""
     B, T, D4 = x_proj.shape
@@ -122,7 +126,8 @@ def slstm_kernel(x_proj, r, h0, c0, n0, m0, *, n_heads: int, chunk: int = 128,
             jax.ShapeDtypeStruct((B, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bB, D), jnp.float32) for _ in range(4)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        name="slstm",
     )(xp3.reshape(B, T // chunk, chunk, D4)[:, :, :, :],
       r, h0, c0, n0, m0)
     h_seq = outs[0].reshape(B, T, D)

@@ -1,14 +1,13 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: prove every (arch × shape × mesh) cell lowers, compiles,
 and fits — and extract the roofline terms from the compiled artifact.
 
 MUST be run as its own process (``python -m repro.launch.dryrun ...``): the
-XLA_FLAGS line above executes before any other import so the 512 placeholder
-devices exist before jax initializes.  ``--all`` orchestrates one subprocess per
-cell (compiles are independent; parallelism via --jobs).
+environment lines below execute before jax is imported, so the 512 placeholder
+devices exist before jax initializes.  The process — and every ``--all``
+child, which inherits the environment — is pinned to the CPU backend, so the
+dry run never takes an accelerator that another process needs.  ``--all``
+orchestrates one subprocess per cell (compiles are independent; parallelism
+via --jobs).
 
 Per cell:
   jax.jit(step_fn, in_shardings, out_shardings, donate).lower(*specs).compile()
@@ -17,6 +16,12 @@ Per cell:
   -> compiled HLO text   (collective bytes for the roofline)
 Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json.
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512").strip()
+
 import argparse
 import dataclasses
 import json

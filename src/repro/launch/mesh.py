@@ -1,8 +1,11 @@
-"""Production mesh definitions.
+"""Mesh definitions.
 
 Functions, not module-level constants, so importing this module never touches jax
 device state (jax locks the device count on first backend init — see dryrun.py).
+Every mesh has Auto axes (``distributed.sharding.make_mesh``).
 
+Local       : (data=N,) over the N devices present — pure data parallel, so
+              the freeze-aware explicit reduce engages (one host, 1 or 4 chips).
 Single pod  : (data=16, model=16)            = 256 chips (one v5e pod)
 Multi-pod   : (pod=2, data=16, model=16)     = 512 chips; the leading "pod" axis
               carries the slow inter-pod links — batch shards over (pod, data),
@@ -10,23 +13,24 @@ Multi-pod   : (pod=2, data=16, model=16)     = 512 chips; the leading "pod" axis
 """
 from __future__ import annotations
 
+import math
+
 import jax
 
-from repro.distributed.sharding import DEFAULT_RULES, MULTIPOD_RULES, ShardingRules
+from repro.distributed.sharding import (DEFAULT_RULES, MULTIPOD_RULES,
+                                        ShardingRules, make_mesh)
+
+
+def make_local_mesh():
+    """Pure-DP ``("data",)`` mesh over every device of this process."""
+    return make_mesh((len(jax.devices()),), ("data",))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()
-    if len(devices) > n:  # 512 placeholder devices, single-pod mesh uses first 256
-        import numpy as np
-        dev = np.asarray(devices[:n]).reshape(shape)
-        return jax.sharding.Mesh(dev, axes)
-    return jax.make_mesh(shape, axes)
+    # 512 placeholder devices: the single-pod mesh uses the first 256
+    return make_mesh(shape, axes, jax.devices()[:math.prod(shape)])
 
 
 def make_fleet_mesh(world_size: int):
@@ -35,7 +39,7 @@ def make_fleet_mesh(world_size: int):
     coordinator's XLA_FLAGS forced into this process.  Pure-DP at every width
     keeps the mesh eligible for the freeze-aware explicit reduce, so a resize
     re-derives the ReducePlan rather than silently falling back to GSPMD."""
-    return jax.make_mesh((world_size,), ("data",))
+    return make_mesh((world_size,), ("data",))
 
 
 def rules_for(mesh) -> ShardingRules:

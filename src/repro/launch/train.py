@@ -19,7 +19,9 @@ import jax
 import repro.configs as configs
 from repro.config import SHAPES, GradESConfig, LoRAConfig, TrainConfig
 from repro.distributed.sharding import use_mesh
-from repro.launch.mesh import make_production_mesh, rules_for
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import (make_local_mesh, make_production_mesh,
+                               rules_for)
 from repro.robustness.faults import FaultPlan, exit_code_for
 from repro.train.loop import Trainer
 
@@ -48,7 +50,11 @@ def main():
                     help="classic validation early stopping baseline")
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--ckpt-every", type=int, default=0)
-    ap.add_argument("--mesh", choices=["none", "single", "multi"], default="none")
+    ap.add_argument("--mesh", choices=["none", "local", "single", "multi"],
+                    default="none",
+                    help="local = pure data parallel over the devices "
+                         "present; single/multi = the 256/512-chip pod "
+                         "meshes")
     ap.add_argument("--kernels", default="auto", choices=["auto", "pallas", "jnp"],
                     help="hot-path backend for the fused GradES kernels AND "
                          "flash attention; auto = Pallas on TPU (shard-mapped "
@@ -123,6 +129,7 @@ def main():
                     help="fleet rendezvous dir (heartbeats + stop files); "
                          "required when --world-size is set")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.world_size > 0 and not args.fleet_dir:
         ap.error("--world-size requires --fleet-dir")
@@ -183,7 +190,8 @@ def main():
         with use_mesh(mesh, rules_for(mesh)), hb:
             res = run()
     elif args.mesh != "none":
-        mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
+        mesh = (make_local_mesh() if args.mesh == "local" else
+                make_production_mesh(multi_pod=(args.mesh == "multi")))
         with use_mesh(mesh, rules_for(mesh)):
             res = run()
     else:

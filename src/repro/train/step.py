@@ -17,13 +17,12 @@ bit-identical to a per-step run that stopped exactly there.
 """
 from __future__ import annotations
 
-import functools
+import dataclasses
 from typing import AbstractSet, Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig, TrainConfig
@@ -35,7 +34,8 @@ from repro.distributed import (active_mesh, active_rules,
                                compress_with_feedback, explicit_reduce_axes,
                                n_compressible, param_partition_specs,
                                reduce_gradients, suspend_mesh)
-from repro.distributed.sharding import mesh_axis_size, model_axis_size
+from repro.distributed.sharding import (ShardingRules, mesh_axis_size,
+                                        model_axis_size)
 from repro.kernels.dispatch import KernelBackend, resolve_backend
 from repro.models import model
 from repro.optim.optimizer import apply_updates, global_norm, lr_at
@@ -86,10 +86,22 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
     """
     static_frozen = frozenset(static_frozen)
     backend = resolve_backend(tcfg.kernels) if backend is None else backend
+    dp_mesh = active_mesh()
+    dp_axes = explicit_reduce_axes(dp_mesh, tcfg)
     mesh = backend.mesh
     rules = active_rules() if mesh is not None else None
-    dp_mesh = active_mesh()
-    dp_axes = explicit_reduce_axes(dp_mesh, tcfg, backend)
+    # attention rides the same resolved backend as the GradES kernels, so
+    # --kernels controls the whole hot path; a non-empty cfg.attn_backend
+    # overrides inside models.common.attn_call_args (DESIGN.md §3b).
+    attn_args = {"backend": backend}
+    if dp_axes is not None and backend.sharded:
+        # Pure data parallel: the loss runs per shard in the manual body
+        # below, where a shard_map-wrapped kernel cannot nest, so attention
+        # takes the kernels unwrapped.  The reduced grads, params and moments
+        # are replicated, so the GradES kernels run whole on every device:
+        # shard_map over replicated specs (rules that map no axis).
+        attn_args = {"backend": dataclasses.replace(backend, mesh=None)}
+        rules = ShardingRules()
     _derived: Dict[str, Any] = {}
 
     def specs_for(params):
@@ -101,11 +113,6 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
             axes = model.param_logical_axes(cfg, model_axis_size(mesh))
             _derived["specs"] = param_partition_specs(params, axes, mesh, rules)
         return _derived["specs"]
-
-    # attention rides the same resolved backend as the GradES kernels, so
-    # --kernels controls the whole hot path; a non-empty cfg.attn_backend
-    # overrides inside models.common.attn_call_args (DESIGN.md §3b).
-    attn_args = {"backend": backend}
 
     def grads_of(params, base_params, batch):
         def f(p):
@@ -158,9 +165,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
                                    metrics)
             return loss, metrics, grads
 
-        _sharded = shard_map(_reduce_body, dp_mesh,
-                             in_specs=(P(), P(), P(dp_axes)),
-                             out_specs=(P(), P(), P()), check_rep=False)
+        _sharded = jax.shard_map(_reduce_body, mesh=dp_mesh,
+                                 in_specs=(P(), P(), P(dp_axes)),
+                                 out_specs=(P(), P(), P()), check_vma=False)
 
         def dispatch_grads(params, base_params, batch):
             bp = base_params if base_params is not None else ()

@@ -4,6 +4,10 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# Tests never use JAX's persistent compilation cache — neither here nor in the
+# entry-point subprocesses they start (which inherit this environment and
+# would otherwise turn it on through repro.launch.cache).
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
 import numpy as np

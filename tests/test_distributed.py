@@ -33,7 +33,7 @@ import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
 from repro.data.pipeline import make_batches
-from repro.distributed.sharding import use_mesh, DEFAULT_RULES
+from repro.distributed.sharding import use_mesh, make_mesh, DEFAULT_RULES
 from repro.models import model
 from repro.train.state import init_train_state
 from repro.train.step import make_train_step
@@ -52,7 +52,7 @@ for b in batches:
     s1, m1 = jax.jit(step)(s1, b)
 
 # sharded on a (2 data, 4 model) mesh
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with use_mesh(mesh, DEFAULT_RULES):
     s2 = state
     fn = jax.jit(step)
@@ -80,11 +80,11 @@ import dataclasses
 from repro.launch import roofline as rf
 from repro.launch.specs import dryrun_train_cfg, train_cell_specs
 from repro.core.grades import build_monitor_spec
-from repro.distributed.sharding import use_mesh, DEFAULT_RULES
+from repro.distributed.sharding import use_mesh, make_mesh, DEFAULT_RULES
 from repro.train.step import make_train_step
 
 cfg = dataclasses.replace(configs.reduced("deepseek-coder-33b"))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cell = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=8)
 tcfg = dataclasses.replace(dryrun_train_cfg(cfg, cell), seq_len=64, global_batch=8)
 with use_mesh(mesh, DEFAULT_RULES):
@@ -113,10 +113,11 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec, grades_update, init_grades_state
+from repro.distributed.sharding import make_mesh
 from repro.kernels import dispatch
 from repro.optim.optimizer import apply_updates, init_opt_state
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 L = 3
 ks = jax.random.split(jax.random.PRNGKey(0), 4)
 params = {{
@@ -202,7 +203,7 @@ import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
 from repro.data.pipeline import make_batches
-from repro.distributed.sharding import use_mesh, DEFAULT_RULES
+from repro.distributed.sharding import use_mesh, make_mesh, DEFAULT_RULES
 from repro.kernels.dispatch import resolve_backend
 from repro.launch.specs import train_cell_specs
 from repro.train.state import init_train_state
@@ -215,7 +216,7 @@ tcfg = TrainConfig(seq_len=32, global_batch=8, steps=10, lr=1e-3,
                                        patience=1))
 state = init_train_state(jax.random.PRNGKey(0), cfg, tcfg)
 spec = build_monitor_spec(state.params)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with use_mesh(mesh, DEFAULT_RULES):
     _, _, state_sh, batch_sh = train_cell_specs(cfg, tcfg, mesh)
     backend = resolve_backend(tcfg.kernels)
@@ -246,7 +247,7 @@ import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
 from repro.data.pipeline import make_batches
-from repro.distributed.sharding import use_mesh, DEFAULT_RULES
+from repro.distributed.sharding import use_mesh, make_mesh, DEFAULT_RULES
 from repro.kernels.dispatch import resolve_backend
 from repro.train.state import init_train_state
 from repro.train.step import make_train_step
@@ -265,7 +266,7 @@ step1 = jax.jit(make_train_step(cfg, tcfg, spec))
 for b in batches:
     s1, m1 = step1(s1, b)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with use_mesh(mesh, DEFAULT_RULES):
     backend = resolve_backend(tcfg.kernels)
     step2 = jax.jit(make_train_step(cfg, tcfg, spec, backend=backend))
@@ -296,6 +297,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.configs as configs
 from repro.config import TrainConfig
 from repro.checkpoint.manager import CheckpointManager
+from repro.distributed.sharding import make_mesh
 from repro.train.state import init_train_state
 
 cfg = configs.reduced("yi-9b")
@@ -306,7 +308,7 @@ try:
     ck = CheckpointManager(d)
     ck.save(1, state, blocking=True)
     # restore with every leaf replicated on a 8-device mesh ("new cluster shape")
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     restored = ck.restore(1, state, shardings=sh)
     for a, b in zip(jax.tree.leaves(jax.device_get(state)),

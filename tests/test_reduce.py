@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 import repro.configs as configs
@@ -28,9 +27,9 @@ from repro.core.grades import _key_path, build_monitor_spec
 from repro.core.partition import (fully_frozen_types, gradient_reduce_plan,
                                   reduce_live_elements, segment_plan)
 from repro.distributed import (compress_with_feedback, dequantize_int8,
-                               explicit_reduce_axes, n_compressible,
-                               quantize_int8, reduce_gradients,
-                               reduce_plan_bytes)
+                               explicit_reduce_axes, make_mesh,
+                               n_compressible, quantize_int8,
+                               reduce_gradients, reduce_plan_bytes)
 from repro.robustness.faults import FaultPlan
 from repro.train.loop import Trainer
 from repro.train.state import init_train_state
@@ -175,7 +174,7 @@ def test_gradient_reduce_plan_drop_slice_and_purity():
 def test_explicit_reduce_axes_eligibility():
     tcfg = _tcfg()
     assert explicit_reduce_axes(None, tcfg) is None
-    mesh1 = jax.make_mesh((1,), ("data",))
+    mesh1 = make_mesh((1,), ("data",))
     assert explicit_reduce_axes(mesh1, tcfg) is None
     assert explicit_reduce_axes(
         mesh1, dataclasses.replace(tcfg, reduce_mode="implicit")) is None
@@ -216,11 +215,12 @@ def test_reduce_gradients_plan_matches_full_on_unit_mesh():
         leaves.append(jnp.asarray(g))
     grads = jax.tree_util.tree_unflatten(treedef, leaves)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     def run(rplan):
-        fn = shard_map(lambda g: reduce_gradients(g, ("data",), rplan),
-                       mesh, in_specs=(P(),), out_specs=P(), check_rep=False)
+        fn = jax.shard_map(lambda g: reduce_gradients(g, ("data",), rplan),
+                           mesh=mesh, in_specs=(P(),), out_specs=P(),
+                           check_vma=False)
         return jax.jit(fn)(grads)
 
     _assert_trees_equal(run(rp), grads, "planned reduce == identity")
@@ -285,7 +285,7 @@ from repro.core.grades import build_monitor_spec
 from repro.core.partition import (fully_frozen_types, gradient_reduce_plan,
                                   segment_plan)
 from repro.data.pipeline import make_batches
-from repro.distributed.sharding import use_mesh, DEFAULT_RULES
+from repro.distributed.sharding import use_mesh, make_mesh, DEFAULT_RULES
 from repro.train.state import init_train_state
 from repro.train.step import make_train_step
 
@@ -295,7 +295,7 @@ tcfg = TrainConfig(seq_len=32, global_batch=8, steps=8, lr=1e-3,
                    grades=GradESConfig(enabled=False))
 L = cfg.n_layers
 batches = list(make_batches(cfg, tcfg, steps=6))
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 state = init_train_state(jax.random.PRNGKey(0), cfg, tcfg)
 spec = build_monitor_spec(state.params)
@@ -346,13 +346,13 @@ import os, shutil, tempfile
 import jax, numpy as np
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
-from repro.distributed.sharding import use_mesh, DEFAULT_RULES
+from repro.distributed.sharding import use_mesh, make_mesh, DEFAULT_RULES
 from repro.train.loop import Trainer
 
 CFG = configs.reduced("qwen3-0.6b")
 base = dict(seq_len=32, global_batch=8, steps=16, lr=3e-3, sync_interval=4,
             reduce_mode="explicit", grades=GradESConfig(enabled=False))
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 
 def trees_equal(a, b, what):
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):

@@ -21,7 +21,7 @@ exit-code-aware :class:`~repro.elastic.policy.RestartPolicy` to every exit:
 
 **Simulated multi-host.**  On CPU the fleet contracts the device runtime into
 the chief (rank 0), whose ``XLA_FLAGS`` force ``world_size`` host-platform
-devices — one per fleet worker — over which ``launch/mesh.py::make_fleet_mesh``
+devices — one per fleet worker — over which ``launch/mesh.py::make_dp_mesh``
 lays a pure-DP ``("data",)`` mesh.  Scale-down is therefore a *real* mesh
 reform: the relaunched chief re-derives batch shardings, the freeze-mask
 ``ReducePlan``, and the plan-independent moment/EF layouts from the boundary

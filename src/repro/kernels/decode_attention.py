@@ -11,12 +11,14 @@ VMEM — but specialized to S = 1 and to *paged* KV:
   index map resolves ``page_table[b, page]`` *before* the kernel body runs and
   the DMA fetches the physical page directly from the pool — no gathered
   contiguous copy of the cache ever exists in HBM.
-* **Split-KV grid.**  Grid ``(B, KV, n_splits, pages_per_split)``: the pages
+* **Split-KV grid.**  Grid ``(B, n_splits, pages_per_split)``: the pages
   of one sequence are partitioned into ``n_splits`` independent splits, each
-  accumulating an online-softmax partial ``(o, logsumexp)`` over its pages in
-  VMEM scratch.  Partials are combined outside the kernel with the standard
-  logsumexp merge (:func:`combine_splits`) — numerically the flash-attention
-  two-level reduction.  Splits whose pages all sit beyond the valid count are
+  accumulating an online-softmax partial ``(o, logsumexp)`` per KV head over
+  its pages in VMEM scratch.  A grid step fetches a whole page, all KV heads
+  in the pool's own layout, so each page is read from HBM once.  Partials
+  are combined outside the kernel with the standard logsumexp merge
+  (:func:`combine_splits`) — numerically the flash-attention two-level
+  reduction.  Splits whose pages all sit beyond the valid count are
   predicated off with ``pl.when`` and drop out of the merge exactly (their
   partial lse is ``NEG_INF``).
 * **kv_valid masking for ragged page tails.**  A sequence of length ``n``
@@ -66,15 +68,16 @@ def gather_pages(pages, table):
 
 def _decode_kernel(table_ref, vc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                    acc_ref, m_ref, l_ref, *, ps: int, spp: int, scale: float):
-    """One (batch row, KV head, split, page) grid step.
+    """One (batch row, split, page) grid step, over every KV head of the page.
 
     The innermost page loop is sequential, so the running (m, l, acc) online-
-    softmax state lives in VMEM scratch across it; at the last page of the
-    split the normalized partial and its logsumexp are written out.
+    softmax state of each head lives in VMEM scratch across it; at the last
+    page of the split the normalized partials and their logsumexps are
+    written out.
     """
     b = pl.program_id(0)
-    s = pl.program_id(2)
-    j = pl.program_id(3)
+    s = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -87,25 +90,26 @@ def _decode_kernel(table_ref, vc_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(slot0 < vc)  # pages fully past the valid tail contribute nothing
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32)               # (Gp, hd)
-        k = k_ref[0].astype(jnp.float32)                  # (ps, hd)
-        v = v_ref[0].astype(jnp.float32)
-        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        cols = slot0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(cols < vc, sc, NEG_INF)            # ragged page tail
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(p, v)
-        m_ref[...] = m_new
+        for h in range(k_ref.shape[2]):                   # static KV heads
+            q = q_ref[0, h].astype(jnp.float32)           # (Gp, hd)
+            k = k_ref[0, :, h, :].astype(jnp.float32)     # (ps, hd)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
+            cols = slot0 + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            sc = jnp.where(cols < vc, sc, NEG_INF)        # ragged page tail
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot(p, v)
+            m_ref[h] = m_new
 
     @pl.when(j == spp - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
+        o_ref[0, :, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, :, 0] = m_ref[...] + jnp.log(l)
 
 
 def combine_splits(o_split, lse_split):
@@ -139,10 +143,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, valid_count, *,
     ``interpret`` None = derived from the backend
     (:func:`repro.kernels.resolve_interpret`).
 
-    The kernel sees the pool as ``(N, page_size, KV·hd)`` (a free reshape)
-    and fetches one ``(page_size, hd)`` head slab per grid step, so every
-    block obeys the TPU tiling rule when ``hd`` is a multiple of 128 or
-    ``KV == 1`` (:func:`repro.kernels.dispatch.paged_decode_restriction`).
+    Each grid step fetches one whole page, ``(page_size, KV, hd)``, as the
+    pool holds it: its last two dims are the pool's own, so the block obeys
+    the TPU tiling rule for any ``KV`` and ``hd``, and the pool is never
+    relaid out.  The kernel takes each head with a static index.
     """
     B, S, KV, G, hd = q.shape
     assert S == 1, q.shape
@@ -158,36 +162,31 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, valid_count, *,
     if Gp != G:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
 
-    kp = k_pages.reshape(N, ps, KV * hd)
-    vp = v_pages.reshape(N, ps, KV * hd)
-    grid = (B, KV, n_splits, spp)
+    grid = (B, n_splits, spp)
     kernel = functools.partial(_decode_kernel, ps=ps, spp=spp,
                                scale=hd ** -0.5)
+    page = pl.BlockSpec((1, ps, KV, hd),
+                        lambda b, s, j, pt, vc: (pt[b, s * spp + j], 0, 0, 0))
     o_split, lse_split = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, Gp, hd),
-                             lambda b, h, s, j, pt, vc: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, hd),
-                             lambda b, h, s, j, pt, vc:
-                             (pt[b, s * spp + j], 0, h)),
-                pl.BlockSpec((1, ps, hd),
-                             lambda b, h, s, j, pt, vc:
-                             (pt[b, s * spp + j], 0, h)),
+                pl.BlockSpec((1, KV, Gp, hd),
+                             lambda b, s, j, pt, vc: (b, 0, 0, 0)),
+                page, page,
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, 1, Gp, hd),
-                             lambda b, h, s, j, pt, vc: (b, h, s, 0, 0)),
-                pl.BlockSpec((1, 1, 1, Gp, 1),
-                             lambda b, h, s, j, pt, vc: (b, h, s, 0, 0)),
+                pl.BlockSpec((1, KV, 1, Gp, hd),
+                             lambda b, s, j, pt, vc: (b, 0, s, 0, 0)),
+                pl.BlockSpec((1, KV, 1, Gp, 1),
+                             lambda b, s, j, pt, vc: (b, 0, s, 0, 0)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((Gp, hd), jnp.float32),
-                pltpu.VMEM((Gp, 1), jnp.float32),
-                pltpu.VMEM((Gp, 1), jnp.float32),
+                pltpu.VMEM((KV, Gp, hd), jnp.float32),
+                pltpu.VMEM((KV, Gp, 1), jnp.float32),
+                pltpu.VMEM((KV, Gp, 1), jnp.float32),
             ],
         ),
         out_shape=[
@@ -197,7 +196,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, valid_count, *,
         interpret=resolve_interpret(interpret),
         name="paged_decode",
     )(page_table.astype(jnp.int32), valid_count.astype(jnp.int32),
-      qr, kp, vp)
+      qr, k_pages, v_pages)
 
     o = combine_splits(o_split, lse_split[..., 0])[:, :, :G]  # (B, KV, G, hd)
     return o[:, None].astype(q.dtype)

@@ -446,13 +446,9 @@ def fused_flash_attention(q, k, v, *, causal: bool, window: int = 0,
                          out_specs=qspec, check_vma=False)(q, k, v, kv_valid)
 
 
-def paged_decode_restriction(q_shape, pages_shape, dtype, *,
-                             compiled: bool = False) -> Optional[str]:
+def paged_decode_restriction(q_shape, pages_shape, dtype) -> Optional[str]:
     """Why the split-KV kernel cannot take this paged decode call — None when
-    it can.  Shape-static, so routing never recompiles the decode block.
-    ``compiled`` adds the TPU lowering's rule: the kernel fetches one KV
-    head of a page as a ``(page_size, hd)`` lane slab, which needs ``hd`` to
-    be a multiple of 128 unless the pool holds a single KV head."""
+    it can.  Shape-static, so routing never recompiles the decode block."""
     if len(q_shape) != 5 or len(pages_shape) != 4:
         return (f"unexpected layout q{tuple(q_shape)} / pages"
                 f"{tuple(pages_shape)} (want (B,1,KV,G,hd) / (N,ps,KV,hd))")
@@ -468,9 +464,6 @@ def paged_decode_restriction(q_shape, pages_shape, dtype, *,
         return f"head_dim {hd} not a multiple of the 8-sublane layout"
     if ps % 8 != 0:
         return f"page_size {ps} not a multiple of the 8-sublane layout"
-    if compiled and pages_shape[2] > 1 and hd % 128 != 0:
-        return (f"head_dim {hd} not a multiple of the 128-lane tile, so the "
-                f"compiled kernel cannot fetch one KV head of a page")
     return None
 
 
@@ -479,8 +472,7 @@ def paged_decode_ok(q, k_pages, backend: KernelBackend) -> bool:
     reason when pallas was forced but the call falls back to the jnp gather."""
     if not backend.use_pallas:
         return False
-    reason = paged_decode_restriction(q.shape, k_pages.shape, q.dtype,
-                                      compiled=not backend.interpret)
+    reason = paged_decode_restriction(q.shape, k_pages.shape, q.dtype)
     if reason is not None:
         _warn_forced_attention_fallback(backend, reason)
         return False

@@ -20,7 +20,7 @@ import repro.configs as configs
 from repro.config import SHAPES, GradESConfig, LoRAConfig, TrainConfig
 from repro.distributed.sharding import use_mesh
 from repro.launch.cache import enable_compile_cache
-from repro.launch.mesh import (make_local_mesh, make_production_mesh,
+from repro.launch.mesh import (make_dp_mesh, make_production_mesh,
                                rules_for)
 from repro.robustness.faults import FaultPlan, exit_code_for
 from repro.train.loop import Trainer
@@ -185,12 +185,11 @@ def main():
         return trainer.train(val_batches=val)
 
     if args.world_size > 0:
-        from repro.launch.mesh import make_fleet_mesh
-        mesh = make_fleet_mesh(args.world_size)
+        mesh = make_dp_mesh(args.world_size)
         with use_mesh(mesh, rules_for(mesh)), hb:
             res = run()
     elif args.mesh != "none":
-        mesh = (make_local_mesh() if args.mesh == "local" else
+        mesh = (make_dp_mesh() if args.mesh == "local" else
                 make_production_mesh(multi_pod=(args.mesh == "multi")))
         with use_mesh(mesh, rules_for(mesh)):
             res = run()

@@ -13,7 +13,7 @@ Keep these tests in this one file.  The topology is described inside a
 fixture, never at import: only one process may load the TPU library at a
 time, so the pytest-xdist worker that is given this file loads it, and the
 other workers never try.  Compiles run in this process with JAX's persistent
-compilation cache off.
+compilation cache off (``conftest.py`` turns it off for every test).
 """
 import contextlib
 import os
@@ -57,18 +57,9 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module", autouse=True)
-def no_compile_cache():
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-
-
 def compile_kernels(fn, shapes, one_chip):
     """Compile ``fn`` for the described chip; Pallas kernels by name."""
+    assert not jax.config.jax_enable_compilation_cache
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     return compiled_kernels(jax.jit(fn).lower(*args).compile().as_text())
 
@@ -118,13 +109,22 @@ def test_flash_fwd_bwd_compiles(batch, masked, one_chip):
     assert bwd == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
 
 
-@pytest.mark.parametrize("page_size,pages_per_split", [(8, 0), (16, 4)])
-def test_paged_decode_compiles(page_size, pages_per_split, one_chip):
-    B, P = 8, 160 // page_size            # 8 slots, 160-token budget each
+@pytest.mark.parametrize("kv,g,hd,page_size,pages_per_split,budget", [
+    (KV, G, HD, 8, 0, 160),     # qwen3-0.6b, 160-token budget per slot
+    (KV, G, HD, 16, 4, 160),    # qwen3-0.6b, several splits per sequence
+    (5, 5, 64, 16, 0, 1024),    # hymba-1.5b: the 1024-token SWA ring
+    (20, 1, 64, 16, 0, 448),    # whisper-large-v3 decoder
+    (8, 8, 112, 16, 0, 256),    # kimi-k2
+], ids=["qwen3-ps8", "qwen3-ps16-split4", "hymba-swa", "whisper", "kimi"])
+def test_paged_decode_compiles(kv, g, hd, page_size, pages_per_split, budget,
+                               one_chip):
+    """Any head layout lowers: the kernel fetches whole pages, whose last
+    two dims (KV, hd) are the pool's own."""
+    B, P = 8, budget // page_size         # 8 slots
     N = 1 + B * P                         # trash page + every slot full
-    shapes = [((B, 1, KV, G, HD), jnp.bfloat16),
-              ((N, page_size, KV, HD), jnp.bfloat16),
-              ((N, page_size, KV, HD), jnp.bfloat16),
+    shapes = [((B, 1, kv, g, hd), jnp.bfloat16),
+              ((N, page_size, kv, hd), jnp.bfloat16),
+              ((N, page_size, kv, hd), jnp.bfloat16),
               ((B, P), jnp.int32), ((B,), jnp.int32)]
     found = compile_kernels(lambda *a: paged_decode_attention(
         *a, pages_per_split=pages_per_split, interpret=False), shapes,

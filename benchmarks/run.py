@@ -15,7 +15,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import (bench_ablation, bench_accuracy, bench_convergence,
-                            bench_efficiency, bench_kernels, bench_roofline)
+                            bench_efficiency)
 
     print("name,us_per_call,derived")
 
@@ -23,10 +23,6 @@ def main() -> None:
         print(f"{name},{us},{derived}", flush=True)
 
     want = lambda n: not args.only or args.only in n
-
-    if want("kernels"):
-        for r in bench_kernels.run():
-            emit(r["name"], r["us_per_call"], r["derived"])
 
     if want("table1"):
         for r in bench_accuracy.run(steps=args.steps):
@@ -48,10 +44,6 @@ def main() -> None:
         rs = bench_convergence.run(steps=args.steps)
         emit("fig1/convergence", 0,
              f"final_loss={rs[-1]['loss']:.3f} frozen={rs[-1]['frozen_frac']:.2f}")
-
-    if want("roofline"):
-        for r in bench_roofline.run():
-            emit(r["name"], r["us_per_call"], r["derived"])
 
     if want("serve"):
         from benchmarks import bench_serve

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig, TrainConfig
+from repro.tracing import span
 
 log = logging.getLogger(__name__)
 
@@ -182,25 +183,29 @@ class Prefetcher:
                     raise
                 log.warning("batch read failed (%s); retry %d/%d in %.3fs",
                             e, attempt + 1, self._retries, delay)
-                if delay > 0:
-                    time.sleep(delay)
+                with span("/repro/data/read_retry", attempt=attempt + 1):
+                    if delay > 0:
+                        time.sleep(delay)
                 delay *= 2
         raise AssertionError("unreachable")
 
     def _build(self, size: int):
-        block: List[Dict[str, np.ndarray]] = []
-        for _ in range(size):
-            if not self._sync and self._stop.is_set():
-                return None  # close() mid-build: stop consuming the source
-            try:
-                block.append(self._next_batch())
-            except StopIteration:
-                break
-        if not block:
-            return None
-        # A short final block (source ran dry mid-block) is yielded as-is —
-        # every batch the source produced gets trained.
-        return self._place(stack_batches(block))
+        with span("/repro/data/build", size=size):
+            block: List[Dict[str, np.ndarray]] = []
+            for _ in range(size):
+                if not self._sync and self._stop.is_set():
+                    return None  # close() mid-build: stop consuming the source
+                try:
+                    block.append(self._next_batch())
+                except StopIteration:
+                    break
+            if not block:
+                return None
+            # A short final block (source ran dry mid-block) is yielded as-is
+            # — every batch the source produced gets trained.
+            block = stack_batches(block)
+            with span("/repro/data/place"):
+                return self._place(block)
 
     def _worker(self):
         try:

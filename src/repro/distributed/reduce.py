@@ -11,7 +11,8 @@ leading dim) and then calls :func:`reduce_gradients`, which emits one
 plan has partially frozen — and skips frozen leaves entirely.  Dropped
 gradients are exactly zero on every shard (``stop_gradient`` upstream), so
 the skip is bit-identical to reducing them; the bytes simply disappear from
-the compiled HLO (measured by ``benchmarks/bench_kernels.py``).
+the compiled HLO (the chip benchmark, ``bench/``, has no multi-chip cell to
+measure them yet).
 
 Eligibility (:func:`explicit_reduce_axes`): the explicit path engages when the
 active mesh is purely data-parallel — every >1-sized axis is a DP axis

@@ -349,8 +349,9 @@ def model_flops_for(cfg, cell, dw_skip_params: float = 0.0) -> float:
 
 def grades_dw_curve(cfg, cell, fracs=(0.0, 0.25, 0.5, 0.75, 1.0)):
     """Modeled step-FLOP curve vs per-layer frozen fraction of the monitored
-    matrices — the quantity the segmented layer scan (Tier 1.5) realizes and
-    ``benchmarks/bench_kernels.py`` checks measured step times against."""
+    matrices — the quantity the segmented layer scan (Tier 1.5) realizes;
+    the chip benchmark (``bench/``) measures the step at a given freeze
+    state."""
     pool = cfg.monitored_param_count()
     base = model_flops_for(cfg, cell)
     rows = []
@@ -372,9 +373,8 @@ def reduce_bytes_model(n_params: float, frozen_params: float = 0.0,
     reduce.py``) removes frozen parameters from the payload outright, and
     int8-EF compression (``distributed/compression.py``) carries 1 byte per
     surviving element on the wire instead of ``dtype_bytes`` (per-matrix fp32
-    scales are O(leaves), negligible).  The measured counterpart is the HLO
-    collective walk over the compiled step (``benchmarks/bench_kernels.py``
-    reduce sweep)."""
+    scales are O(leaves), negligible).  The measured counterpart is
+    :func:`collective_bytes` over the compiled step's HLO."""
     live = max(float(n_params) - float(frozen_params), 0.0)
     wire = 1.0 if compress else float(dtype_bytes)
     return 2.0 * live * wire

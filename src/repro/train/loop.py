@@ -85,6 +85,7 @@ from repro.optim.optimizer import (align_moments, align_packed_tree,
                                    expand_packed_tree_host)
 from repro.robustness.faults import FaultyBatchSource, tag_grad_faults
 from repro.robustness.harness import FaultActuator, GracefulShutdown
+from repro.tracing import span
 from repro.train.state import (TrainState, init_train_state,
                                steps_completed)
 from repro.train.step import make_eval_step, make_multi_step
@@ -305,15 +306,25 @@ class Trainer:
             plan.  The expansion happens on the host (numpy scatter of the
             device_get'd packed rows), never re-materializing the full
             buffers in device memory."""
-            save_opt = expand_moments_host(st.opt, st.params, tcfg, trainable)
-            if save_opt is not st.opt:
-                st = dataclasses.replace(st, opt=save_opt)
-            if st.ef_error is not None:
-                save_ef = expand_packed_tree_host(st.ef_error, st.params,
-                                                  trainable)
-                if save_ef is not st.ef_error:
-                    st = dataclasses.replace(st, ef_error=save_ef)
+            with span("/repro/train/host_layout"):
+                save_opt = expand_moments_host(st.opt, st.params, tcfg,
+                                               trainable)
+                if save_opt is not st.opt:
+                    st = dataclasses.replace(st, opt=save_opt)
+                if st.ef_error is not None:
+                    save_ef = expand_packed_tree_host(st.ef_error, st.params,
+                                                      trainable)
+                    if save_ef is not st.ef_error:
+                        st = dataclasses.replace(st, ef_error=save_ef)
             return st
+
+        def guard_snapshot(st, step):
+            """The numerics guard's rollback target: the state in the
+            checkpoint layout, pulled whole to host RAM."""
+            with span("/repro/train/guard_snapshot", step=step):
+                st = _checkpoint_state(st)
+                with span("/repro/train/state_to_host"):
+                    return jax.device_get(st)
 
         # Multiplicative LR backoff applied by the numerics guard: each
         # rollback halves (by rollback_lr_backoff) the LR of the re-dispatched
@@ -395,8 +406,7 @@ class Trainer:
         # verified finite.  Rollback = device_put it back and re-derive the
         # freeze artifacts from its masks — the same pure functions a restart
         # runs, so replay is bit-deterministic.
-        snapshot = (jax.device_get(_checkpoint_state(state))
-                    if guard_on else None)
+        snapshot = guard_snapshot(state, start_step) if guard_on else None
         snapshot_step = start_step
         best_val, val_bad = float("inf"), 0
         # --- watchdog state (block-granular; see module docstring) ---
@@ -410,6 +420,10 @@ class Trainer:
         straggler_hit = False
 
         def drain(inflight: _Inflight) -> bool:
+            with span("/repro/train/drain", step=inflight.start):
+                return settle(inflight)
+
+        def settle(inflight: _Inflight) -> bool:
             """Bulk device_get of one block's stacked metrics; returns True if
             Tier-2 (all monitored matrices frozen) was observed."""
             nonlocal ema_dt, last_done, blocks_drained, last_row, \
@@ -518,7 +532,8 @@ class Trainer:
                     preempt = True
                     break
                 try:
-                    block = next(blocks)
+                    with span("/repro/train/prefetch_wait", step=bstart):
+                        block = next(blocks)
                 except StopIteration:
                     break
                 # An externally-supplied iterator can run dry mid-block; the
@@ -544,7 +559,8 @@ class Trainer:
                     dispatched_sizes.add(bsize)
                     compile_pending = True
                 t_dispatch = time.perf_counter()
-                state, metrics = step_fn(state, block)
+                with span("/repro/train/dispatch", step=bstart):
+                    state, metrics = step_fn(state, block)
                 cur = _Inflight(start=bstart, size=bsize, metrics=metrics,
                                 dispatched_at=t_dispatch)
                 prev_s, s = s, bstart + bsize
@@ -565,82 +581,98 @@ class Trainer:
                              and s // tcfg.checkpoint_every
                              > prev_s // tcfg.checkpoint_every)
                 if tier2 or need_t1 or need_val or need_ckpt:
-                    # Sync boundary: settle the just-dispatched block too.
-                    tier2 = drain(pending) or tier2
-                    pending = None
-                    if tripped is not None:
-                        break
-                    if tier2:
-                        stop = "all_frozen"
-                        break
-                    # Refresh the static freeze artifacts at repartition
-                    # boundaries AND before a checkpoint: the saved moment
-                    # layout must equal the pure function of the masks being
-                    # saved, so a resume re-derives it exactly.  Evaluating
-                    # the (quantized) pure function more often cannot add
-                    # recompiles — only distinct values count.
-                    if (need_t1 or need_ckpt) and tcfg.grades.enabled \
-                            and tcfg.grades.static_repartition:
-                        new_static, new_plan, new_rows, new_rplan = \
-                            freeze_artifacts(
-                                jax.device_get(state.grades.frozen))
-                        # row masks and the reduce plan are pure functions of
-                        # (static, plan, spec), so the two comparisons below
-                        # cover them too
-                        if new_static != static_frozen or new_plan != plan:
-                            old_trainable = trainable
-                            static_frozen, plan, row_frozen, reduce_plan = (
-                                new_static, new_plan, new_rows, new_rplan)
-                            trainable = trainable_mask(
-                                state.params, spec, static_frozen, row_frozen)
-                            new_opt = align_moments(state.opt, state.params,
-                                                    tcfg, trainable,
-                                                    old_trainable)
-                            if new_opt is not state.opt:
-                                state = dataclasses.replace(state, opt=new_opt)
-                            state = _align_ef(state, trainable, old_trainable)
-                            step_fn = compile_step(static_frozen, plan,
-                                                   row_frozen, reduce_plan)
-                            recompiles += 1
-                            compile_pending = True  # paid at the next dispatch
-                    if need_val:
-                        # One eval per boundary; a non-improving result
-                        # accrues one patience count per val_interval multiple
-                        # the block crossed (the K=1 plateau cadence), while
-                        # an improving result counts as a single improvement —
-                        # mid-block states were never materialized, so they
-                        # cannot be evaluated separately.  Patience state
-                        # (best_val/val_bad) is in-memory only: a resumed
-                        # val-ES run restarts it.
-                        vl = float(np.mean([
-                            float(eval_fn(state.params, state.base_params, vb))
-                            for vb in val_batches]))
-                        if vl < best_val - tcfg.val_delta:
-                            best_val, val_bad = vl, 0
-                        else:
-                            val_bad += val_crossings
-                        if val_bad >= tcfg.val_patience:
-                            stop = "val_es"
+                    with span("/repro/train/boundary", step=bstart):
+                        # Sync boundary: settle the just-dispatched block.
+                        tier2 = drain(pending) or tier2
+                        pending = None
+                        if tripped is not None:
                             break
-                    if need_ckpt:
-                        self.ckpt.save(s, _checkpoint_state(state))
-                        if fplan is not None and \
-                                fplan.corrupt_mode(s) is not None:
-                            # Planned corruption targets the *renamed* step —
-                            # wait for the async write, then damage it.
-                            self.ckpt.wait()
-                            act.after_checkpoint(s, tcfg.checkpoint_dir)
-                    if guard_on:
-                        # Everything drained above verified finite — this
-                        # state is a safe rollback target.
-                        snapshot = jax.device_get(_checkpoint_state(state))
-                        snapshot_step = s
-                    # Boundary work (eval forward passes, the checkpoint's
-                    # device_get, a Tier-1 recompile) is host/aux time, not
-                    # block compute: restart the completion-delta clock so the
-                    # next block's per-step estimate excludes it (no false
-                    # straggler flags).
-                    last_done = time.perf_counter()
+                        if tier2:
+                            stop = "all_frozen"
+                            break
+                        # Refresh the static freeze artifacts at repartition
+                        # boundaries AND before a checkpoint: the saved moment
+                        # layout must equal the pure function of the masks
+                        # being saved, so a resume re-derives it exactly.
+                        # Evaluating the (quantized) pure function more often
+                        # cannot add recompiles — only distinct values count.
+                        if (need_t1 or need_ckpt) and tcfg.grades.enabled \
+                                and tcfg.grades.static_repartition:
+                            with span("/repro/train/freeze_masks",
+                                      step=bstart):
+                                new_static, new_plan, new_rows, new_rplan = \
+                                    freeze_artifacts(
+                                        jax.device_get(state.grades.frozen))
+                            # row masks and the reduce plan are pure functions
+                            # of (static, plan, spec), so the two comparisons
+                            # below cover them too
+                            if new_static != static_frozen or new_plan != plan:
+                                with span("/repro/train/repartition",
+                                          step=bstart):
+                                    old_trainable = trainable
+                                    (static_frozen, plan, row_frozen,
+                                     reduce_plan) = (new_static, new_plan,
+                                                     new_rows, new_rplan)
+                                    trainable = trainable_mask(
+                                        state.params, spec, static_frozen,
+                                        row_frozen)
+                                    new_opt = align_moments(
+                                        state.opt, state.params, tcfg,
+                                        trainable, old_trainable)
+                                    if new_opt is not state.opt:
+                                        state = dataclasses.replace(
+                                            state, opt=new_opt)
+                                    state = _align_ef(state, trainable,
+                                                      old_trainable)
+                                    step_fn = compile_step(
+                                        static_frozen, plan, row_frozen,
+                                        reduce_plan)
+                                recompiles += 1
+                                compile_pending = True  # paid at next dispatch
+                        if need_val:
+                            # One eval per boundary; a non-improving result
+                            # accrues one patience count per val_interval
+                            # multiple the block crossed (the K=1 plateau
+                            # cadence), while an improving result counts as a
+                            # single improvement — mid-block states were never
+                            # materialized, so they cannot be evaluated
+                            # separately.  Patience state (best_val/val_bad)
+                            # is in-memory only: a resumed val-ES run restarts
+                            # it.
+                            with span("/repro/train/eval", step=bstart):
+                                vl = float(np.mean([
+                                    float(eval_fn(state.params,
+                                                  state.base_params, vb))
+                                    for vb in val_batches]))
+                            if vl < best_val - tcfg.val_delta:
+                                best_val, val_bad = vl, 0
+                            else:
+                                val_bad += val_crossings
+                            if val_bad >= tcfg.val_patience:
+                                stop = "val_es"
+                                break
+                        if need_ckpt:
+                            with span("/repro/train/checkpoint", step=bstart):
+                                self.ckpt.save(s, _checkpoint_state(state))
+                                if fplan is not None and \
+                                        fplan.corrupt_mode(s) is not None:
+                                    # Planned corruption targets the *renamed*
+                                    # step — wait for the async write, then
+                                    # damage it.
+                                    self.ckpt.wait()
+                                    act.after_checkpoint(s,
+                                                         tcfg.checkpoint_dir)
+                        if guard_on:
+                            # Everything drained above verified finite — this
+                            # state is a safe rollback target.
+                            snapshot = guard_snapshot(state, bstart)
+                            snapshot_step = s
+                        # Boundary work (eval forward passes, the checkpoint's
+                        # device_get, a Tier-1 recompile) is host/aux time,
+                        # not block compute: restart the completion-delta
+                        # clock so the next block's per-step estimate excludes
+                        # it (no false straggler flags).
+                        last_done = time.perf_counter()
                 if exhausted:
                     break
               # settle the trailing block (skipped when a trip already broke
@@ -670,18 +702,19 @@ class Trainer:
                 # artifact from its masks (identical to a cold restart from a
                 # checkpoint of that boundary), then recompile with the
                 # backed-off LR.
-                state = jax.device_put(snapshot)
-                static_frozen, plan, row_frozen, reduce_plan = \
-                    freeze_artifacts(jax.device_get(state.grades.frozen))
-                trainable = trainable_mask(state.params, spec, static_frozen,
-                                           row_frozen)
-                new_opt = align_moments(state.opt, state.params, tcfg,
-                                        trainable)
-                if new_opt is not state.opt:
-                    state = dataclasses.replace(state, opt=new_opt)
-                state = _align_ef(state, trainable)
-                step_fn = compile_step(static_frozen, plan, row_frozen,
-                                       reduce_plan)
+                with span("/repro/train/rollback", step=tripped[0]):
+                    state = jax.device_put(snapshot)
+                    static_frozen, plan, row_frozen, reduce_plan = \
+                        freeze_artifacts(jax.device_get(state.grades.frozen))
+                    trainable = trainable_mask(state.params, spec,
+                                               static_frozen, row_frozen)
+                    new_opt = align_moments(state.opt, state.params, tcfg,
+                                            trainable)
+                    if new_opt is not state.opt:
+                        state = dataclasses.replace(state, opt=new_opt)
+                    state = _align_ef(state, trainable)
+                    step_fn = compile_step(static_frozen, plan, row_frozen,
+                                           reduce_plan)
                 recompiles += 1
                 dispatched_sizes = set()
                 compile_pending = False
@@ -693,7 +726,9 @@ class Trainer:
                 # dispatched work is settled and finite — write a synchronous
                 # boundary checkpoint and exit with a resumable stop reason.
                 if self.ckpt is not None:
-                    self.ckpt.save(s, _checkpoint_state(state), blocking=True)
+                    with span("/repro/train/checkpoint", step=s):
+                        self.ckpt.save(s, _checkpoint_state(state),
+                                       blocking=True)
                 stop = ("straggler_abort"
                         if straggler_hit and not shutdown.requested
                         else "preempted")

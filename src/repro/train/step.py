@@ -198,7 +198,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
         fault_gain = batch.pop("fault_gain", None)
         comm_gain = batch.pop("comm_gain", None)
         params = state.params
-        loss, metrics, grads = dispatch_grads(params, state.base_params, batch)
+        with jax.named_scope("fwd_bwd"):
+            loss, metrics, grads = dispatch_grads(params, state.base_params,
+                                                  batch)
 
         if fault_target is not None and fault_gain is not None:
             grads = splice_fault(grads, fault_gain)
@@ -210,20 +212,23 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
             if fp is not None and comm_gain is not None:
                 fault_index = fp.comm_target_index(
                     n_compressible(grads, trainable))
-            grads, ef_error = compress_with_feedback(
-                grads, ef_error, trainable=trainable,
-                fault_gain=comm_gain if fault_index is not None else None,
-                fault_index=fault_index)
+            with jax.named_scope("ef_compress"):
+                grads, ef_error = compress_with_feedback(
+                    grads, ef_error, trainable=trainable,
+                    fault_gain=comm_gain if fault_index is not None else None,
+                    fault_index=fault_index)
 
         pspecs = specs_for(params)
-        grades, frozen = grades_update(state.grades, grads, spec, tcfg.grades,
-                                       tcfg.steps, backend=backend,
-                                       param_specs=pspecs)
-        new_params, new_opt = apply_updates(params, grads, state.opt, tcfg,
-                                            trainable=trainable, spec=spec,
-                                            group_frozen=frozen,
-                                            backend=backend,
-                                            param_specs=pspecs)
+        with jax.named_scope("grades_monitor"):
+            grades, frozen = grades_update(state.grades, grads, spec,
+                                           tcfg.grades, tcfg.steps,
+                                           backend=backend,
+                                           param_specs=pspecs)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = apply_updates(
+                params, grads, state.opt, tcfg, trainable=trainable,
+                spec=spec, group_frozen=frozen, backend=backend,
+                param_specs=pspecs)
         metrics = dict(metrics)
         metrics["grad_norm"] = global_norm(grads)
         metrics["frozen_frac"] = frozen_fraction(frozen)

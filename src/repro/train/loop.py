@@ -149,6 +149,60 @@ def _plan_blocks(ranges: Sequence[Tuple[int, int]], k: int
     return out
 
 
+#: the most bytes of device-to-host copies in flight at once: on one TPU v5e
+#: host an 8 GB state comes over in ~3 s in 2 GiB groups and in 6-11 s all
+#: at once, while ``pinned_host`` destinations are slower still (PERF.md)
+D2H_GROUP_BYTES = 2 << 30
+
+
+@dataclass(frozen=True)
+class HostSnapshot:
+    """A state tree held in host memory (:func:`snapshot_to_host`)."""
+
+    leaves: List[np.ndarray]    # in tree order
+    treedef: Any
+    targets: List[Any]          # each leaf's sharding, None if uncommitted
+
+
+def _d2h_groups(leaves, limit: int):
+    """Consecutive runs of ``leaves`` of at most ``limit`` bytes each (a
+    larger leaf alone)."""
+    group, n = [], 0
+    for x in leaves:
+        if group and n + x.nbytes > limit:
+            yield group
+            group, n = [], 0
+        group.append(x)
+        n += x.nbytes
+    if group:
+        yield group
+
+
+def snapshot_to_host(tree) -> HostSnapshot:
+    """Copy ``tree`` to host memory in the layout it has (row-packed moments,
+    placeholders and error-feedback buffers as the compiled step holds them,
+    no expansion), at most :data:`D2H_GROUP_BYTES` in flight at a time, and
+    return once every copy has landed, so the tree's buffers may be donated
+    afterwards.  The host holds each leaf once: a replicated leaf is read
+    from one device, a sharded one shard by shard.  The copy is the span
+    ``/repro/train/state_to_host`` with the snapshot's host ``bytes``."""
+    leaves, treedef = jax.tree.flatten(tree)
+    with span("/repro/train/state_to_host",
+              bytes=sum(x.nbytes for x in leaves)):
+        host = [h for group in _d2h_groups(leaves, D2H_GROUP_BYTES)
+                for h in jax.device_get(group)]
+    return HostSnapshot(host, treedef,
+                        [x.sharding if x.committed else None for x in leaves])
+
+
+def restore_snapshot(snap: HostSnapshot):
+    """The tree :func:`snapshot_to_host` copied, back on the device: each leaf
+    on the sharding it had, and one that was not committed to a device
+    uncommitted again, so ``jit`` may still move it onto the step's mesh."""
+    return jax.tree.unflatten(snap.treedef, [
+        jax.device_put(h, t) for h, t in zip(snap.leaves, snap.targets)])
+
+
 class _ChainedSource:
     """Chains per-range batch sources, tolerating exceptions from the active
     range: unlike a generator or ``itertools.chain``, a raise (an injected or
@@ -319,12 +373,10 @@ class Trainer:
             return st
 
         def guard_snapshot(st, step):
-            """The numerics guard's rollback target: the state in the
-            checkpoint layout, pulled whole to host RAM."""
+            """The numerics guard's rollback target: the state as the step
+            holds it, copied to host memory."""
             with span("/repro/train/guard_snapshot", step=step):
-                st = _checkpoint_state(st)
-                with span("/repro/train/state_to_host"):
-                    return jax.device_get(st)
+                return snapshot_to_host(st)
 
         # Multiplicative LR backoff applied by the numerics guard: each
         # rollback halves (by rollback_lr_backoff) the LR of the re-dispatched
@@ -400,14 +452,14 @@ class Trainer:
         stop = "budget"
         rollbacks = 0
         skips: List[Tuple[int, int]] = []
-        # Boundary snapshot for the numerics guard: the full state pulled to
-        # host RAM through the checkpoint path (plan-independent moment
-        # layout), refreshed at each sync boundary once every drained block
-        # verified finite.  Rollback = device_put it back and re-derive the
-        # freeze artifacts from its masks — the same pure functions a restart
-        # runs, so replay is bit-deterministic.
+        # Boundary snapshot for the numerics guard: the whole state copied to
+        # host memory in the step's own (packed) layout, refreshed at
+        # each sync boundary once every drained block verified finite; the
+        # layout it was packed to rides along.  Rollback = put it back and
+        # re-derive the freeze artifacts from its masks — the same pure
+        # functions a restart runs, so replay is bit-deterministic.
         snapshot = guard_snapshot(state, start_step) if guard_on else None
-        snapshot_step = start_step
+        snapshot_step, snapshot_trainable = start_step, trainable
         best_val, val_bad = float("inf"), 0
         # --- watchdog state (block-granular; see module docstring) ---
         ema_dt: Optional[float] = None
@@ -666,7 +718,7 @@ class Trainer:
                             # Everything drained above verified finite — this
                             # state is a safe rollback target.
                             snapshot = guard_snapshot(state, bstart)
-                            snapshot_step = s
+                            snapshot_step, snapshot_trainable = s, trainable
                         # Boundary work (eval forward passes, the checkpoint's
                         # device_get, a Tier-1 recompile) is host/aux time,
                         # not block compute: restart the completion-delta
@@ -701,18 +753,20 @@ class Trainer:
                 # Restore the boundary snapshot and re-derive every static
                 # artifact from its masks (identical to a cold restart from a
                 # checkpoint of that boundary), then recompile with the
-                # backed-off LR.
+                # backed-off LR.  The snapshot is packed to the layout of its
+                # boundary, which the re-derived one equals unless masks
+                # froze since the last refresh (a val-only boundary).
                 with span("/repro/train/rollback", step=tripped[0]):
-                    state = jax.device_put(snapshot)
+                    state = restore_snapshot(snapshot)
                     static_frozen, plan, row_frozen, reduce_plan = \
                         freeze_artifacts(jax.device_get(state.grades.frozen))
                     trainable = trainable_mask(state.params, spec,
                                                static_frozen, row_frozen)
                     new_opt = align_moments(state.opt, state.params, tcfg,
-                                            trainable)
+                                            trainable, snapshot_trainable)
                     if new_opt is not state.opt:
                         state = dataclasses.replace(state, opt=new_opt)
-                    state = _align_ef(state, trainable)
+                    state = _align_ef(state, trainable, snapshot_trainable)
                     step_fn = compile_step(static_frozen, plan, row_frozen,
                                            reduce_plan)
                 recompiles += 1

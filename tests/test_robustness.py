@@ -382,6 +382,40 @@ def test_rollback_replay_is_deterministic(rollback_run):
             assert l0[h["step"]] == h["loss"]
 
 
+def test_rollback_restores_packed_moments_bit_identically():
+    """A trip after a repartition boundary, with Tier-1.5 row-packed moments
+    live: the MLP of layer 0 is frozen from the start (so its moments are
+    packed to the live rows), and "layers/wq" freezes whole at once, so the
+    boundary at step 4 re-plans it to a placeholder.  The snapshot taken
+    there holds that packed layout; the rollback restores it without
+    re-packing and the whole recovery replays bit for bit."""
+    tcfg = _tcfg(fault_plan=FaultPlan.parse(["nan_grad@6"]),
+                 grades=GradESConfig(enabled=True, alpha=0.0, tau=1e-9,
+                                     tau_overrides={"layers/wq": 1e9}))
+
+    def run():
+        trainer = Trainer(CFG, tcfg, repartition_interval=4, log_every=4)
+        state = trainer.init_state()
+        frozen = dict(state.grades.frozen)
+        for name in ("layers/w_gate", "layers/w_up", "layers/w_down"):
+            frozen[name] = frozen[name].at[0].set(True)
+        state.grades.frozen = frozen
+        return trainer.train(state=state)
+
+    r1, r2 = run(), run()
+    for r in (r1, r2):
+        assert r.stop_reason == "budget"
+        assert r.rollbacks == 1
+        assert r.steps_run == tcfg.steps - tcfg.sync_interval
+        assert [h["step"] for h in r.history if "rollback" in h] == [4.0]
+    m, p = r1.state.opt.m["layers"], r1.state.params["layers"]
+    assert m["w_up"].shape == (1,) + p["w_up"].shape[1:]   # packed rows
+    assert m["wq"].shape == (1,)                         # placeholder
+    _assert_trees_equal(r1.state.params, r2.state.params, "params")
+    _assert_trees_equal(r1.state.opt, r2.state.opt, "opt")
+    _assert_trees_equal(r1.state.grades, r2.state.grades, "grades")
+
+
 def test_rollback_budget_exhausted_aborts():
     plan = FaultPlan.parse(["nan_grad@6"])
     res = Trainer(CFG, _tcfg(fault_plan=plan, max_rollbacks=0),

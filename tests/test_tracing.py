@@ -97,8 +97,10 @@ def test_guard_snapshot_at_start_and_each_boundary(traced_run):
     for snap in snaps:
         kids = [s for s in rec.spans if s[3].get("parent") == snap[0]
                 and snap[1] <= s[1] and s[2] <= snap[2]]
-        assert [k[0] for k in sorted(kids, key=lambda s: s[1])] == \
-            [T + "host_layout", T + "state_to_host"]
+        # the state as the step holds it, copied to pinned host memory:
+        # no checkpoint-layout expansion on the way
+        assert [(k[0], k[3]["bytes"] > 0) for k in kids] == \
+            [(T + "state_to_host", True)]
 
 
 def test_boundary_parents_its_work(traced_run):

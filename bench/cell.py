@@ -5,15 +5,18 @@ files behind them:
 
 * ``bench/configs/<config>.json`` -- the model as it is run, under the keys
   of its published ``config.json``, with ``source``, ``reduced``,
-  ``assumed``, ``departures``, ``deployment`` and ``reference`` (the plain
-  reference under ``bench/references/``).
+  ``assumed``, ``departures``, ``deployment`` and ``family``: the model
+  family, whose module under ``bench/families/`` alone reads the widths and
+  whose plain reference is the module of that name under
+  ``bench/references/``.
 * ``bench/traffic/<traffic>.json`` -- the fine-tune job: row length, the
   trainer's and optimizer's settings, and which matrices are frozen at
   set-up.
 * ``bench/cells/<cell>.json`` -- what belongs to the pair alone: the rows per
   step that fit one chip, and the comparison limits of ``correct``.
 
-Adding a cell, a configuration or a mix adds files; no code changes.
+Adding a cell, a configuration, a mix or a model family adds files; no code
+changes.
 """
 from __future__ import annotations
 
@@ -22,6 +25,8 @@ import math
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Dict, List
+
+import families
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -42,6 +47,11 @@ class Cell:
 
     # ------------------------------------------------------------- model
     @property
+    def family(self):
+        """The configuration's model family (``bench/families/``)."""
+        return families.load(self.config)
+
+    @property
     def n_layers(self) -> int:
         return int(self.config["num_hidden_layers"])
 
@@ -57,6 +67,16 @@ class Cell:
     def rows(self) -> int:
         """Rows per step over all chips of the cell."""
         return int(self.pair["rows_per_chip"]) * self.chips
+
+    @property
+    def tokens_per_step(self) -> int:
+        return self.rows * self.seq_len
+
+    @property
+    def causal_pairs(self) -> int:
+        """Query-key pairs of causal attention over every row of a step."""
+        S = self.seq_len
+        return self.rows * S * (S + 1) // 2
 
     @property
     def steps_per_period(self) -> int:
@@ -94,8 +114,9 @@ def load_cell(name: str) -> Cell:
         raise SystemExit(f"unknown workload {name!r}; BENCHMARK.json has "
                          f"{[w['name'] for w in bench['workloads']]}")
     cfg = next(c for c in bench["configs"] if c["name"] == entry["config"])
-    return Cell(name=name, chips=int(entry["chips"]),
-                config=_load(ROOT / cfg["file"]),
+    config = _load(ROOT / cfg["file"])
+    families.load(config, where=cfg["file"])
+    return Cell(name=name, chips=int(entry["chips"]), config=config,
                 traffic=_load(BENCH_DIR / "traffic"
                               / f"{entry['traffic']}.json"),
                 pair=_load(BENCH_DIR / "cells" / f"{name}.json"))
@@ -103,17 +124,7 @@ def load_cell(name: str) -> Cell:
 
 def model_config(cell: Cell):
     """The program's ``ModelConfig`` for the configuration file."""
-    from repro.config import ModelConfig
-    c = cell.config
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=cell.n_layers,
-        d_model=int(c["hidden_size"]), n_heads=int(c["num_attention_heads"]),
-        n_kv_heads=int(c["num_key_value_heads"]),
-        d_ff=int(c["intermediate_size"]), vocab=cell.vocab,
-        head_dim=int(c["head_dim"]), rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        dtype=c["compute_dtype"], param_dtype=c["param_dtype"])
+    return cell.family.model_config(cell)
 
 
 def train_config(cell: Cell, seed: int):

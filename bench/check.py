@@ -4,7 +4,7 @@ The program's first sync block and the plain reference's run over the same
 weights and batches give, for each step, the loss, and after the block, per
 parameter leaf, the norm of the change and the square root of the sum of
 AdamW's second moment (the gradients as the optimizer got them), and per
-monitored layer row the GradES monitor.  Compared, each against its limit:
+monitored row the GradES monitor.  Compared, each against its limit:
 
 * ``loss_gap``: the largest absolute gap of a step's loss;
 * ``grad_gap``, ``change_gap``: by the worst leaf, the gap between the
@@ -12,7 +12,8 @@ monitored layer row the GradES monitor.  Compared, each against its limit:
   leaf or of the median leaf, whichever is larger.  Leaves whose reference
   gradient is under a thousandth of the median leaf's are left out: they are
   frozen, or move by round-off alone;
-* ``monitor_gap``: the same, by the worst live monitored layer row;
+* ``monitor_gap``: the same, by the worst live monitored row: ``name[l]``
+  of a group frozen per layer, ``name[l,e]`` per layer and expert;
 * ``frozen_moved``: the largest change of a weight in a frozen row, which
   must be exactly 0.
 """
@@ -50,10 +51,11 @@ def readings(prog: Dict[str, Any], ref: Dict[str, Any]) -> Dict[str, Any]:
         prog["change"], ref["change"], leaves)
     pm, rm = {}, {}
     for name, rows in ref["monitor"].items():
-        for i, r in enumerate(rows):
-            if r > 0:
-                pm[f"{name}[{i}]"] = prog["monitor"][name][i]
-                rm[f"{name}[{i}]"] = r
+        rows, got = np.asarray(rows), np.asarray(prog["monitor"][name])
+        for at in np.ndindex(rows.shape):
+            if rows[at] > 0:
+                key = f"{name}[{','.join(map(str, at))}]"
+                pm[key], rm[key] = float(got[at]), float(rows[at])
     out["monitor_gap"], out["monitor_gap_at"] = _worst(pm, rm, sorted(rm))
     out["frozen_moved"] = max(prog.get("frozen_moved", {}).values(),
                               default=0.0)

@@ -1,8 +1,8 @@
 """Share of the window in which the chip was idle while the trainer took the
 numerics guard's boundary snapshot (the program's span
-``/repro/train/guard_snapshot``: the state expanded to the checkpoint layout
-and pulled whole to host RAM), averaged over the cell's chips
-(``bench/spans.py``)."""
+``/repro/train/guard_snapshot``: the state as the compiled step holds it,
+packed moments and all, copied to host RAM in groups of at most 2 GiB, with
+no expansion), averaged over the cell's chips (``bench/spans.py``)."""
 import spans
 
 

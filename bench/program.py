@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.grades import build_monitor_spec, init_grades_state
+from repro.core.grades import (build_monitor_spec, get_path,
+                               init_grades_state)
 from repro.core.partition import (fully_frozen_types, plan_row_masks,
                                   segment_plan, trainable_mask)
 from repro.optim.optimizer import init_opt_state
@@ -51,20 +52,22 @@ COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
 MIN_PERIODS = 2
 
 
-def group_name(matrix: str) -> str:
-    return f"layers/{matrix}"
-
-
 def build_state(cell: Cell, params, tcfg) -> TrainState:
     """The program's state around the benchmark's weights, with the cell's
-    frozen rows written into ``grades.frozen`` and AdamW's moments laid out
+    frozen flags, at each monitor group's own mask shape as the model family
+    maps them, written into ``grades.frozen``, and AdamW's moments laid out
     (packed to live rows) as the trainer derives them from those masks."""
     cfg = model_config(cell)
     spec = build_monitor_spec(params)
     frozen = {name: np.zeros(spec.mask_shape(params, name), bool)
               for name in spec.groups}
-    for matrix, rows in cell.frozen_rows().items():
-        frozen[group_name(matrix)] = np.asarray(rows, bool)
+    for name, mask in cell.family.frozen_masks(cell).items():
+        want = frozen[name].shape if name in frozen else None
+        if mask.shape != want:
+            raise ValueError(f"{cell.name}: the frozen mask of {name} has "
+                             f"shape {mask.shape}; the program's monitor "
+                             f"group has {want}")
+        frozen[name] = mask
     static = fully_frozen_types(frozen)
     plan = segment_plan(frozen, spec, cfg.n_layers, tcfg.segment_max)
     trainable = trainable_mask(params, spec, static,
@@ -147,9 +150,9 @@ class CompileCounter:
 
 def first_block_readings(state: TrainState, p0, cell: Cell) -> Dict[str, Any]:
     """What ``correct`` reads from the state after the first block, per
-    parameter leaf (paths joined by ``/``) and per monitored layer row."""
-    frozen_rows = {group_name(m): np.asarray(r, bool)
-                   for m, r in cell.frozen_rows().items()}
+    parameter leaf (paths joined by ``/``) and per monitored row."""
+    groups = build_monitor_spec(p0).groups
+    masks = cell.family.frozen_masks(cell)
 
     @jax.jit
     def read(params, p0, v, last_norm):
@@ -159,12 +162,12 @@ def first_block_readings(state: TrainState, p0, cell: Cell) -> Dict[str, Any]:
         vnorm = jax.tree.map(lambda x: jnp.sqrt(jnp.sum(x.astype(jnp.float32))),
                              v)
         moved = {}
-        for name, rows in frozen_rows.items():
-            leaf = name.split("/")[1]
-            diff = jnp.abs(params["layers"][leaf] - p0["layers"][leaf])
+        for name, mask in masks.items():
+            (path,), _ = groups[name]       # one weight leaf a group
+            diff = jnp.abs(get_path(params, path) - get_path(p0, path))
             moved[name] = jnp.max(jnp.where(
-                jnp.asarray(rows).reshape((-1,) + (1,) * (diff.ndim - 1)),
-                diff, 0.0))
+                jnp.asarray(mask).reshape(
+                    mask.shape + (1,) * (diff.ndim - mask.ndim)), diff, 0.0))
         return change, vnorm, moved, last_norm
 
     change, vnorm, moved, last_norm = jax.device_get(read(

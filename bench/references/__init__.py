@@ -1,5 +1,5 @@
-"""Plain references, one module per model family, found by the ``reference``
-key of a configuration file: ``bench/references/<reference>.py`` defines
+"""Plain references, one module per model family, found by the ``family``
+key of a configuration file: ``bench/references/<family>.py`` defines
 ``Reference(config, traffic, frozen_rows, precision)``."""
 from __future__ import annotations
 
@@ -7,6 +7,6 @@ import importlib
 
 
 def load(cell, precision: str = "float32"):
-    mod = importlib.import_module(f"references.{cell.config['reference']}")
+    mod = importlib.import_module(f"references.{cell.config['family']}")
     return mod.Reference(cell.config, cell.traffic, cell.frozen_rows(),
                          precision)

@@ -22,7 +22,8 @@ def test_one_layer_step_flops_by_hand():
 
 def test_frozen_share_and_grades_bytes():
     cell = tiny_cell()                  # 4 layers: MLP frozen in layers 0, 1
-    sizes = flops.matrix_sizes(cell)
+    sizes = {"wq": 64 * 64, "wk": 64 * 32, "wv": 64 * 32, "wo": 64 * 64,
+             "w_gate": 64 * 128, "w_up": 64 * 128, "w_down": 128 * 64}
     per_layer = sum(sizes.values())
     frozen = 4 * (sizes["wq"] + sizes["wk"]) + 2 * 3 * 64 * 128
     assert flops.frozen_share(cell) == frozen / (4 * per_layer)

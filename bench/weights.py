@@ -1,13 +1,8 @@
-"""Seeded weights of a dense decoder, made on the device in one jitted call.
+"""Seeded weights of a cell's model, made on the device in one jitted call.
 
 The benchmark makes the weights; the program and the reference are each
-handed them.  Layout (``L`` layers stacked on the leading axis, ``d`` the
-hidden size, ``q = heads * head_dim``, ``kv = kv_heads * head_dim``):
-
-    embed (V, d)      layers/attn_norm (L, d)   layers/wq (L, d, q)
-    final_norm (d,)   layers/wk, wv (L, d, kv)  layers/wo (L, q, d)
-    lm_head (d, V)    layers/mlp_norm (L, d)    layers/w_gate, w_up (L, d, f)
-    (untied only)                               layers/w_down (L, f, d)
+handed them.  Which leaves there are, and their shapes, is the model
+family's (``bench/families/<family>.py::shapes``).
 
 Matrices are truncated normals (two standard deviations) of standard
 deviation ``1/sqrt(fan_in)``; norm gains are offsets from 1 and start at 0.
@@ -18,6 +13,8 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+import families
 
 Shapes = Dict[str, Any]
 
@@ -30,27 +27,7 @@ def seed_key(seed: int) -> jax.Array:
 
 def shapes(config: Dict[str, Any]) -> Shapes:
     """Leaf shapes with their fan-in axis (None for norm gains)."""
-    L = int(config["num_hidden_layers"])
-    d, f = int(config["hidden_size"]), int(config["intermediate_size"])
-    hd = int(config["head_dim"])
-    q = int(config["num_attention_heads"]) * hd
-    kv = int(config["num_key_value_heads"]) * hd
-    V = int(config["vocab_size"])
-    out: Shapes = {
-        "embed": ((V, d), -1),
-        "layers": {
-            "attn_norm": ((L, d), None),
-            "wq": ((L, d, q), -2), "wk": ((L, d, kv), -2),
-            "wv": ((L, d, kv), -2), "wo": ((L, q, d), -2),
-            "mlp_norm": ((L, d), None),
-            "w_gate": ((L, d, f), -2), "w_up": ((L, d, f), -2),
-            "w_down": ((L, f, d), -2),
-        },
-        "final_norm": ((d,), None),
-    }
-    if not config["tie_word_embeddings"]:
-        out["lm_head"] = ((d, V), -2)
-    return out
+    return families.load(config).shapes(config)
 
 
 def _leaf(key, spec: Tuple, dtype) -> jax.Array:

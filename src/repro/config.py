@@ -29,15 +29,38 @@ FAMILIES = ("dense", "moe", "encdec", "hybrid", "xlstm")
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts block settings (GShard-style token-choice top-k)."""
+    """Mixture-of-experts block settings, token-choice top-k.
 
-    n_experts: int = 8
+    ``scoring="softmax"`` is the GShard layer (``models/moe.py::moe_block``):
+    softmax top-k, capacity drops, aux and z losses.  ``scoring="sigmoid"``
+    is DeepSeek-V3's (``moe.held_expert_block``): experts are selected by
+    sigmoid score plus a per-expert correction bias (the ``router_bias``
+    buffer, which nothing trains) and weighted by the unbiased scores of the
+    chosen ones, normalised over them when ``norm_topk`` and scaled by
+    ``routed_scale``; no token is dropped and no aux loss is added.  That
+    layer holds experts ``[held_offset, held_offset + n_held)`` of the router's
+    ``n_experts`` (one chip's share under expert parallelism; ``n_held`` 0 =
+    all) and adds ``shared_d_ff``-wide shared experts for every token.
+    """
+
+    n_experts: int = 8             # the router's width
     top_k: int = 2
     d_ff: int = 0                  # per-expert hidden size
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
     aux_loss: float = 1e-2
     group_size: int = 1024         # tokens per dispatch group (bounds scatter size)
+    scoring: str = "softmax"       # "softmax" | "sigmoid"
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    shared_d_ff: int = 0           # the shared experts as one SwiGLU; 0 = none
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -71,6 +94,19 @@ class ModelConfig:
     n_frames: int = 1500           # audio frame stub length fed to the encoder
     # --- MoE ---
     moe: Optional[MoEConfig] = None
+    # --- latent attention (MLA, DeepSeek-V2/V3) when kv_lora_rank > 0: q is
+    # x·W_q with heads of qk_nope + qk_rope dims; keys and values come from a
+    # kv_lora_rank-wide latent (RMSNorm'd) plus one shared rope head ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- leading dense layers (DeepSeek-V3's first_k_dense_replace): SwiGLU
+    # layers of width dense_d_ff before the stacked scan; ``n_layers`` counts
+    # the scan's layers (``params["layers"]``, what the GradES planner
+    # segments), so the model has n_dense_layers + n_layers ---
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
     # --- hybrid (hymba): parallel attention + mamba heads ---
     ssm: Optional[SSMConfig] = None
     # --- xLSTM: ratio of mLSTM:sLSTM blocks handled by the xlstm stack ---
@@ -92,7 +128,13 @@ class ModelConfig:
     attn_backend: str = ""
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def resolved_head_dim(self) -> int:
+        if self.mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.d_model // self.n_heads
 
     @property
@@ -102,6 +144,13 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.resolved_head_dim
+
+    @property
+    def attn_out_dim(self) -> int:
+        """Width of the attention output that W_o maps back to d_model."""
+        if self.mla:
+            return self.n_heads * self.v_head_dim
+        return self.q_dim
 
     @property
     def dt_rank(self) -> int:
@@ -124,10 +173,11 @@ class ModelConfig:
         accounting).  Active-expert counting matches
         ``active_param_count``'s FLOP convention."""
         d = self.d_model
-        attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+        attn = _attn_params(self)
         if self.moe is not None:
             mlp = 3 * d * self.moe.d_ff * self.moe.top_k \
                 + d * self.moe.n_experts  # router is monitored too
+            mlp += 3 * d * self.moe.shared_d_ff
         elif self.family == "xlstm":
             mlp = 2 * d * max(self.d_ff, 2 * d)
         else:
@@ -139,24 +189,38 @@ class ModelConfig:
             ssm = (d * 2 * di + di * (self.dt_rank + 2 * self.ssm.state_dim)
                    + self.dt_rank * di + di * self.ssm.state_dim + di * d
                    + di * self.ssm.conv_width)
-        return self.n_layers * (attn + mlp + ssm)
+        dense = self.n_dense_layers * (attn + 3 * d * self.dense_d_ff)
+        return self.n_layers * (attn + mlp + ssm) + dense
 
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self), indent=2)
 
 
+def _attn_params(cfg: ModelConfig) -> int:
+    """Weights of one layer's attention projections."""
+    d = cfg.d_model
+    if cfg.mla:
+        r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        return (d * cfg.q_dim + d * (r + dr)
+                + r * cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+                + cfg.attn_out_dim * d)
+    return d * cfg.q_dim + 2 * d * cfg.kv_dim + cfg.q_dim * d
+
+
 def _param_count(cfg: ModelConfig, *, active_only: bool) -> int:
-    d, hd = cfg.d_model, cfg.resolved_head_dim
-    attn = d * cfg.q_dim + 2 * d * cfg.kv_dim + cfg.q_dim * d
+    d = cfg.d_model
+    attn = _attn_params(cfg)
     if cfg.moe is not None:
-        e = cfg.moe.top_k if active_only else cfg.moe.n_experts
+        e = cfg.moe.top_k if active_only else cfg.moe.held
         mlp = 3 * d * cfg.moe.d_ff * e + d * cfg.moe.n_experts  # experts + router
+        mlp += 3 * d * cfg.moe.shared_d_ff
     elif cfg.family == "xlstm":
         mlp = 2 * d * max(cfg.d_ff, 2 * d)  # up/down proj around the recurrent core
     else:
         n_mats = 3 if cfg.mlp_act == "swiglu" else 2
         mlp = n_mats * d * cfg.d_ff
-    per_layer = attn + mlp + 2 * d  # + norms
+    norms = 2 * d + cfg.kv_lora_rank
+    per_layer = attn + mlp + norms
     if cfg.ssm is not None:
         di = cfg.ssm.expand * d
         per_layer += d * 2 * di + di * (cfg.dt_rank + 2 * cfg.ssm.state_dim)
@@ -166,6 +230,7 @@ def _param_count(cfg: ModelConfig, *, active_only: bool) -> int:
         # q/k/v/o for mLSTM + gate projections; folded into attn above approximately.
         pass
     total = cfg.n_layers * per_layer
+    total += cfg.n_dense_layers * (attn + 3 * d * cfg.dense_d_ff + norms)
     if cfg.n_encoder_layers:
         enc_per_layer = attn + 2 * d * cfg.d_ff + 2 * d          # gelu mlp
         dec_cross = d * cfg.q_dim + 2 * d * cfg.kv_dim + cfg.q_dim * d + d

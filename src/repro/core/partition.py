@@ -82,8 +82,9 @@ def trainable_mask(params, spec: MonitorSpec,
     Leaf values (consumed by ``optim/optimizer.py``):
 
     * ``True``  — fully live: full-shape m/v buffers.
-    * ``False`` — statically frozen (whole type, or every row): 1-element
-      moment placeholder.
+    * ``False`` — statically frozen (whole type, or every row), or one of
+      the model's buffers (``models/moe.py::BUFFERS``): 1-element moment
+      placeholder, no update.
     * ``np.ndarray`` (bool, granularity shape, True = **live** row) — the
       Tier-1.5 per-row case: m/v store only the live rows
       (``(n_live,) + trailing``), freeing 8 bytes/param for frozen rows
@@ -100,13 +101,15 @@ def trainable_mask(params, spec: MonitorSpec,
     whole-type behavior (also used under multi-device meshes, where packed
     rows would break the divisibility of the moment shardings).
     """
+    # imported here: repro.models reaches back into repro.core
+    from repro.models.moe import BUFFERS
     frozen_paths = _static_paths(spec, static_frozen)
     p2g = spec.path_to_group
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     leaves = []
     for kp, leaf in flat:
         path = _key_path(kp)
-        if path in frozen_paths:
+        if path in frozen_paths or str(path[-1]) in BUFFERS:
             leaves.append(False)
             continue
         group = p2g.get(path)

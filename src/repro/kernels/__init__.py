@@ -9,10 +9,13 @@ from typing import Optional
 
 import jax
 
-#: the ``name`` of every pallas_call; compiled HLO names each Mosaic custom
-#: call after it, with transformation prefixes such as ``jvp_``/``transpose_``
+#: the ``name`` of every pallas_call (megablox's grouped matmuls, which the
+#: held experts run, are named after their functions ``gmm`` and ``tgmm``);
+#: compiled HLO names each Mosaic custom call after it, with transformation
+#: prefixes such as ``jvp_``/``transpose_``
 KERNEL_NAMES = ("grades_norm", "masked_adamw", "masked_sgd", "flash_fwd",
-                "flash_dq", "flash_dkv", "paged_decode", "slstm")
+                "flash_dq", "flash_dkv", "paged_decode", "slstm", "tgmm",
+                "gmm")
 _CUSTOM_CALL = re.compile(
     r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"')
 

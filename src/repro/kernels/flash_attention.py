@@ -8,6 +8,8 @@ covering everything ``attention()`` actually uses:
   (B, T, KV, hd)`` (the model-layer layout); the wrapper re-lays q into
   per-KV-head row blocks ``(B, KV, G·S, hd)`` so grouped query heads share
   their KV tile in VMEM without ever materializing repeated K/V in HBM.
+  ``v`` may carry a head dim of its own (latent attention: q·k over 192,
+  P·V over 128); the output, ``dO`` and ``dV`` tiles take v's.
 * **Masking** — causal, sliding ``window``, and a ``kv_valid (B, T)`` mask
   (padded cache slots / ragged lengths), all applied in-kernel with the shared
   ``masking.NEG_INF`` constant so parity tests compare identical semantics.
@@ -174,7 +176,7 @@ def _forward(q, k, v, mask, *, causal: bool, window: int, block_q: int,
              block_k: int, interpret: bool):
     """Returns (o external layout, (o_rows, lse) residuals in row layout)."""
     B, S, KV, G, hd = q.shape
-    T = k.shape[1]
+    T, hdv = k.shape[1], v.shape[-1]
     bq, Sp, bk, Tp = _tile_geometry(S, T, block_q, block_k)
     R = G * Sp
     qr = _q_to_rows(q, Sp)
@@ -192,18 +194,18 @@ def _forward(q, k, v, mask, *, causal: bool, window: int, block_q: int,
             pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j)),
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, hdv), lambda b, h, i, j: (b, h, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, hdv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
+            jax.ShapeDtypeStruct((B, KV, R, hdv), q.dtype),
             jax.ShapeDtypeStruct((B, KV, R, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, hd), jnp.float32),
+            pltpu.VMEM((bq, hdv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
@@ -288,7 +290,7 @@ def _dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _backward(q, k, v, mask, o_rows, lse, do, *, causal: bool, window: int,
               block_q: int, block_k: int, interpret: bool):
     B, S, KV, G, hd = q.shape
-    T = k.shape[1]
+    T, hdv = k.shape[1], v.shape[-1]
     bq, Sp, bk, Tp = _tile_geometry(S, T, block_q, block_k)
     R = G * Sp
     qr = _q_to_rows(q, Sp)
@@ -304,12 +306,14 @@ def _backward(q, k, v, mask, o_rows, lse, do, *, causal: bool, window: int,
 
     mask_spec = pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j))
     q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0))
+    o_spec = pl.BlockSpec((1, 1, bq, hdv), lambda b, h, i, j: (b, h, i, 0))
+    k_spec = pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h, j, 0))
+    v_spec = pl.BlockSpec((1, 1, bk, hdv), lambda b, h, i, j: (b, h, j, 0))
     row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
     dqr = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
         grid=(B, KV, R // bq, Tp // bk),
-        in_specs=[mask_spec, q_spec, kv_spec, kv_spec, q_spec, row_spec,
+        in_specs=[mask_spec, q_spec, k_spec, v_spec, o_spec, row_spec,
                   row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), jnp.float32),
@@ -322,17 +326,19 @@ def _backward(q, k, v, mask, o_rows, lse, do, *, causal: bool, window: int,
     # this KV head, accumulating the GQA group reduction into dk/dv.
     t_mask = pl.BlockSpec((1, 1, bk), lambda b, h, j, i: (b, 0, j))
     t_q = pl.BlockSpec((1, 1, bq, hd), lambda b, h, j, i: (b, h, i, 0))
-    t_kv = pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, i: (b, h, j, 0))
+    t_o = pl.BlockSpec((1, 1, bq, hdv), lambda b, h, j, i: (b, h, i, 0))
+    t_k = pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, i: (b, h, j, 0))
+    t_v = pl.BlockSpec((1, 1, bk, hdv), lambda b, h, j, i: (b, h, j, 0))
     t_row = pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0))
     dkr, dvr = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
         grid=(B, KV, Tp // bk, R // bq),
-        in_specs=[t_mask, t_q, t_kv, t_kv, t_q, t_row, t_row],
-        out_specs=[t_kv, t_kv],
+        in_specs=[t_mask, t_q, t_k, t_v, t_o, t_row, t_row],
+        out_specs=[t_k, t_v],
         out_shape=[jax.ShapeDtypeStruct((B, KV, Tp, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, Tp, hd), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, KV, Tp, hdv), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
-                        pltpu.VMEM((bk, hd), jnp.float32)],
+                        pltpu.VMEM((bk, hdv), jnp.float32)],
         interpret=interpret,
         name="flash_dkv",
     )(mp, qr, kr, vr, dor, lse, delta)
@@ -378,8 +384,10 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     interpret=None):
     """Flash attention in the model layout, differentiable end to end.
 
-    q: (B, S, KV, G, hd); k, v: (B, T, KV, hd); kv_valid: optional (B, T)
-    bool/0-1 validity mask.  Returns (B, S, KV, G, hd).  Matches
+    q, k: (B, S, KV, G, hd), (B, T, KV, hd); v: (B, T, KV, hdv), whose head
+    dim may differ from the query/key one (latent attention: q·k over 192,
+    P·V over 128); scores are scaled by ``hd ** -0.5``.  kv_valid: optional
+    (B, T) bool/0-1 validity mask.  Returns (B, S, KV, G, hdv).  Matches
     ``models.attention.full_attention`` (and its gradients) for causal,
     windowed, GQA, and padded-length cases; S/T need not be block multiples.
     ``interpret`` None = derived from the backend
@@ -387,7 +395,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     """
     B, S, KV, G, hd = q.shape
     T = k.shape[1]
-    assert k.shape == (B, T, KV, hd) and v.shape == (B, T, KV, hd), \
+    assert k.shape == (B, T, KV, hd) and v.shape[:3] == (B, T, KV), \
         (q.shape, k.shape, v.shape)
     mask = (jnp.ones((B, T), jnp.float32) if kv_valid is None
             else kv_valid.astype(jnp.float32))

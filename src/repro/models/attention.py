@@ -2,8 +2,9 @@
 
 Layout conventions
   q        : (B, S, KV, G, hd)   G = n_heads // n_kv_heads (grouped query heads)
-  k, v     : (B, T, KV, hd)
-  output   : (B, S, KV, G, hd)
+  k        : (B, T, KV, hd)
+  v        : (B, T, KV, hdv)     hdv = hd but for latent attention (MLA)
+  output   : (B, S, KV, G, hdv)  scores scaled by hd ** -0.5
 
 ``attention()`` is the production entry point: it routes through the kernel
 backend machinery (``kernels/dispatch.py``, same ``"pallas" | "jnp" | "auto"``
@@ -78,7 +79,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     qs = q.reshape(B, nq, q_chunk, KV, G, hd)
     ks = k.reshape(B, nkv, kv_chunk, KV, hd)
-    vs = v.reshape(B, nkv, kv_chunk, KV, hd)
+    hdv = v.shape[-1]
+    vs = v.reshape(B, nkv, kv_chunk, KV, hdv)
     valid = (None if kv_valid is None
              else kv_valid.reshape(B, nkv, kv_chunk))
 
@@ -86,7 +88,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True, window: int = 0,
         qi, qb = inp  # index, (B, qc, KV, G, hd)
         m0 = jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, q_chunk, KV, G, hd), jnp.float32)
+        a0 = jnp.zeros((B, q_chunk, KV, G, hdv), jnp.float32)
 
         def live_block(ki, state):
             m, l, acc = state
@@ -128,7 +130,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True, window: int = 0,
         return carry, out.astype(q.dtype)
 
     _, blocks = jax.lax.scan(q_block, None, (jnp.arange(nq), qs.transpose(1, 0, 2, 3, 4, 5)))
-    out = blocks.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, KV, G, hd)
+    out = blocks.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, KV, G, hdv)
     # fully-masked rows: exactly zero on every backend (masking.rows_alive)
     return zero_dead_rows(out, rows_alive(kv_valid, S, causal=causal,
                                           window=window))

@@ -40,27 +40,38 @@ def supports_segment_plan(cfg: ModelConfig) -> bool:
     return _mod(cfg) is transformer
 
 
-def forward(params, cfg: ModelConfig, batch: Dict[str, Any], *, remat: str = "none",
-            attn_args=None, plan=None):
+def _forward(params, cfg: ModelConfig, batch: Dict[str, Any], *, remat: str,
+             attn_args, plan):
+    """(logits, aux, counts): ``counts`` the model's per-step work counts,
+    a held-expert layer's ``expert_load`` (L, held experts), from the layer
+    scan's outputs."""
     if cfg.family == "encdec":
         logits, aux = encdec.forward(params, cfg, batch["tokens"], batch["frames"],
                                      remat=remat, attn_args=attn_args)
     elif supports_segment_plan(cfg):
-        logits, aux = transformer.forward(params, cfg, batch["tokens"], remat=remat,
-                                          attn_args=attn_args, plan=plan)
+        return transformer.forward(params, cfg, batch["tokens"], remat=remat,
+                                   attn_args=attn_args, plan=plan)
     else:
         logits, aux = _mod(cfg).forward(params, cfg, batch["tokens"], remat=remat,
                                         attn_args=attn_args)
-    return logits, aux
+    return logits, aux, {}
+
+
+def forward(params, cfg: ModelConfig, batch: Dict[str, Any], *, remat: str = "none",
+            attn_args=None, plan=None):
+    return _forward(params, cfg, batch, remat=remat, attn_args=attn_args,
+                    plan=plan)[:2]
 
 
 def loss_fn(params, batch: Dict[str, Any], cfg: ModelConfig, *, remat: str = "none",
             attn_args=None, plan=None):
-    logits, aux = forward(params, cfg, batch, remat=remat, attn_args=attn_args,
-                          plan=plan)
+    """(loss, metrics); a model that routes to held experts adds their
+    ``expert_load`` (L, held experts) to the metrics."""
+    logits, aux, counts = _forward(params, cfg, batch, remat=remat,
+                                   attn_args=attn_args, plan=plan)
     ce = cross_entropy(logits, batch["labels"])
     loss = ce + aux
-    return loss, {"loss": loss, "ce": ce, "aux_loss": aux}
+    return loss, {"loss": loss, "ce": ce, "aux_loss": aux, **counts}
 
 
 def init_cache(params, cfg: ModelConfig, batch: int, max_len: int):

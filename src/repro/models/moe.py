@@ -14,6 +14,11 @@ instead compute each routed token's slot by a cumsum rank over the one-hot assig
 Experts are sharded over the "expert" logical axis (expert parallelism); tokens are
 processed in groups of ``group_size`` so the scatter buffers stay small and the
 dispatch is local to each data shard.
+
+:func:`held_expert_block` is DeepSeek-V3's layer (``MoEConfig.scoring ==
+"sigmoid"``) on the slice of experts one chip holds: it routes over all the
+router's experts, drops nothing, and runs its held experts as grouped
+matmuls over the assignments actually routed to them.
 """
 from __future__ import annotations
 
@@ -22,9 +27,25 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 from repro.config import MoEConfig
 from repro.distributed.sharding import logical_constraint
+from repro.kernels import resolve_interpret
+from repro.models.mlp import swiglu
+
+#: megablox tiles (rows, contraction, output columns) of the held experts'
+#: grouped matmuls
+GMM_TILING = (512, 512, 512)
+
+#: parameter leaves that are buffers: the forward reads them and nothing
+#: trains them, so they keep no optimizer moments and take no update or
+#: weight decay (DeepSeek-V3's expert-selection correction bias)
+BUFFERS = frozenset({"router_bias"})
+
+#: leaves a sigmoid-scored layer keeps in float32 inside the layer body: the
+#: router's, whose scores pick the experts
+SIGMOID_FLOAT32_LEAVES = frozenset({"router"}) | BUFFERS
 
 
 def capacity(group_tokens: int, cfg: MoEConfig) -> int:
@@ -127,3 +148,84 @@ def moe_block_ref(x, params, cfg: MoEConfig):
         weight = jnp.where(e == ex, w, 0.0).sum(axis=1)
         out = out + y * weight[:, None].astype(y.dtype)
     return out.reshape(B, S, D)
+
+
+def sigmoid_route(x, router, bias, cfg: MoEConfig):
+    """DeepSeek-V3 ``noaux_tc`` routing, in float32.
+
+    x: (T, D); router: (D, E); bias: (E,), the correction bias.  Experts are
+    *selected* by ``sigmoid(x·W_r) + bias``, top-``top_k`` over all ``E``, and
+    *weighted* by the unbiased sigmoid scores of the chosen ones, normalised
+    to sum to 1 (``norm_topk``) and scaled by ``routed_scale``.  The bias
+    moves only the selection: no gradient reaches it.  Returns (weights
+    (T, k) float32, experts (T, k) int32).
+    """
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, experts = jax.lax.top_k(choice, cfg.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.norm_topk:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return weights * cfg.routed_scale, experts
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """Rows of ``lhs`` (M, K), sorted by group, times their group's ``rhs``
+    (G, K, N), over the first ``sum(sizes)`` rows only (megablox ``gmm``,
+    which beat ``jax.lax.ragged_dot`` on a TPU v5e at Moonlight's widths);
+    later rows of the result are undefined."""
+    m, k = lhs.shape
+    tm, tk, tn = GMM_TILING
+    tiling = (min(tm, m), min(tk, k), min(tn, rhs.shape[2]))
+    return gmm(lhs, rhs, sizes, lhs.dtype, tiling,
+               interpret=resolve_interpret(None))
+
+
+def held_expert_block(x, params, cfg: MoEConfig):
+    """DeepSeek-V3 expert layer on the experts this chip holds.
+
+    x: (B, S, D).  params: ``router`` (D, E) and ``router_bias`` (E,) over all
+    ``E = cfg.n_experts``; ``w_gate``/``w_up`` (E_h, D, F), ``w_down``
+    (E_h, F, D) of the held experts ``[held_offset, held_offset + E_h)``;
+    ``shared_gate``/``shared_up`` (D, F_s), ``shared_down`` (F_s, D).
+
+    Each token's picks among the held experts are sorted by expert and run
+    through three grouped matmuls sized by the counts actually routed, so
+    the device work follows the assignments (plus at most a tile of padding
+    an expert); no token is dropped.  Picks of experts held elsewhere add no
+    term here.  The shared experts are added for every token.  Returns
+    (out (B, S, D), load (E_h,) int32: tokens x picks routed to each held
+    expert).
+    """
+    B, S, D = x.shape
+    T, k, E_h = B * S, cfg.top_k, cfg.held
+    xt = x.reshape(T, D)
+    weights, experts = sigmoid_route(xt, params["router"],
+                                     params["router_bias"], cfg)
+    local = experts.reshape(-1) - cfg.held_offset
+    local = jnp.where((local >= 0) & (local < E_h), local, E_h)
+    load = jnp.zeros((E_h + 1,), jnp.int32).at[local].add(1)[:E_h]
+    # held picks first, by expert: at most min(k, E_h) a token are held
+    M = T * min(k, E_h)
+    M = -(-M // GMM_TILING[0]) * GMM_TILING[0] if M > GMM_TILING[0] else M
+    order = jnp.argsort(local, stable=True)
+    if M > order.shape[0]:
+        order = jnp.pad(order, (0, M - order.shape[0]))
+    order = order[:M]
+    valid = (jnp.arange(M) < load.sum())[:, None]
+    tok = order // k
+    # rows past the routed assignments are undefined in every grouped
+    # matmul, forward and backward: the selects keep them out of both
+    xs = jnp.where(valid, xt[tok], 0).astype(x.dtype)
+    h = jax.nn.silu(grouped_matmul(xs, params["w_gate"], load)) \
+        * grouped_matmul(xs, params["w_up"], load)
+    y = jnp.where(valid, grouped_matmul(h, params["w_down"], load), 0)
+    y = y * weights.reshape(-1)[order].astype(y.dtype)[:, None]
+    routed = jnp.zeros((T, D), y.dtype).at[tok].add(y)
+    out = routed.reshape(B, S, D)
+    if cfg.shared_d_ff:
+        out = out + swiglu(x, params["shared_gate"], params["shared_up"],
+                           params["shared_down"])
+    return out, load

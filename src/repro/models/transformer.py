@@ -35,31 +35,59 @@ from repro.models.common import (apply_rope, attn_call_args, cross_entropy,
 # Parameters
 # ---------------------------------------------------------------------------
 
-def init_layer_params(key, cfg: ModelConfig, n_layers: int, dtype: str) -> Dict[str, Any]:
+def init_layer_params(key, cfg: ModelConfig, n_layers: int, dtype: str,
+                      *, dense: bool = False) -> Dict[str, Any]:
+    """One stack of ``n_layers`` layers; ``dense`` makes the leading dense
+    layers of a mixture-of-experts model (a SwiGLU of ``cfg.dense_d_ff``)."""
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
     ks = iter(jax.random.split(key, 16))
     L = n_layers
-    p: Dict[str, Any] = {
-        "attn_norm": jnp.zeros((L, d), jnp.dtype(dtype)),
-        "wq": init_dense(next(ks), (L, d, qd), dtype=dtype),
-        "wk": init_dense(next(ks), (L, d, kvd), dtype=dtype),
-        "wv": init_dense(next(ks), (L, d, kvd), dtype=dtype),
-        "wo": init_dense(next(ks), (L, qd, d), dtype=dtype),
-        "mlp_norm": jnp.zeros((L, d), jnp.dtype(dtype)),
-    }
-    if cfg.moe is not None:
-        e, f = cfg.moe.n_experts, cfg.moe.d_ff
+    p: Dict[str, Any] = {"attn_norm": jnp.zeros((L, d), jnp.dtype(dtype)),
+                         "wq": init_dense(next(ks), (L, d, qd), dtype=dtype)}
+    if cfg.mla:
+        r, H = cfg.kv_lora_rank, cfg.n_heads
         p.update({
-            "router": init_dense(next(ks), (L, d, e), dtype=dtype),
+            "wkv_a": init_dense(next(ks), (L, d, r + cfg.qk_rope_head_dim),
+                                dtype=dtype),
+            "kv_norm": jnp.zeros((L, r), jnp.dtype(dtype)),
+            "wkv_b": init_dense(next(ks), (L, r, H * (cfg.qk_nope_head_dim
+                                                      + cfg.v_head_dim)),
+                                dtype=dtype),
+        })
+    else:
+        p.update({"wk": init_dense(next(ks), (L, d, kvd), dtype=dtype),
+                  "wv": init_dense(next(ks), (L, d, kvd), dtype=dtype)})
+    p.update({
+        "wo": init_dense(next(ks), (L, cfg.attn_out_dim, d), dtype=dtype),
+        "mlp_norm": jnp.zeros((L, d), jnp.dtype(dtype)),
+    })
+    if cfg.moe is not None and not dense:
+        e, f = cfg.moe.held, cfg.moe.d_ff
+        p.update({
+            "router": init_dense(next(ks), (L, d, cfg.moe.n_experts),
+                                 dtype=dtype),
             "w_gate": init_dense(next(ks), (L, e, d, f), dtype=dtype),
             "w_up": init_dense(next(ks), (L, e, d, f), dtype=dtype),
             "w_down": init_dense(next(ks), (L, e, f, d), in_axis=-2, dtype=dtype),
         })
+        if cfg.moe.scoring == "sigmoid":
+            # the correction bias: small and nonzero, so that weighting by
+            # the biased score would show; nothing ever trains it
+            p["router_bias"] = 0.05 * jax.random.normal(
+                next(ks), (L, cfg.moe.n_experts), jnp.dtype(dtype))
+        if cfg.moe.shared_d_ff:
+            fs = cfg.moe.shared_d_ff
+            p.update({
+                "shared_gate": init_dense(next(ks), (L, d, fs), dtype=dtype),
+                "shared_up": init_dense(next(ks), (L, d, fs), dtype=dtype),
+                "shared_down": init_dense(next(ks), (L, fs, d), dtype=dtype),
+            })
     elif cfg.mlp_act == "swiglu":
+        f = cfg.dense_d_ff if dense else cfg.d_ff
         p.update({
-            "w_gate": init_dense(next(ks), (L, d, cfg.d_ff), dtype=dtype),
-            "w_up": init_dense(next(ks), (L, d, cfg.d_ff), dtype=dtype),
-            "w_down": init_dense(next(ks), (L, cfg.d_ff, d), dtype=dtype),
+            "w_gate": init_dense(next(ks), (L, d, f), dtype=dtype),
+            "w_up": init_dense(next(ks), (L, d, f), dtype=dtype),
+            "w_down": init_dense(next(ks), (L, f, d), dtype=dtype),
         })
     else:  # gelu
         p.update({
@@ -79,6 +107,9 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
         "layers": init_layer_params(k2, cfg, cfg.n_layers, dtype),
         "final_norm": jnp.zeros((cfg.d_model,), jnp.dtype(dtype)),
     }
+    if cfg.n_dense_layers:
+        params["dense_layers"] = init_layer_params(
+            k4, cfg, cfg.n_dense_layers, dtype, dense=True)
     if not cfg.tie_embeddings:
         params["lm_head"] = init_dense(k3, (cfg.d_model, cfg.vocab), dtype=dtype)
     return params
@@ -90,7 +121,8 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
 # re-gather the per-head layout every layer (decode: the whole KV cache) — worse
 # than replicating the projections.  ``model_size=None`` (tests, single device)
 # keeps the TP axes.
-def layer_param_axes(cfg: ModelConfig, model_size: Optional[int] = None) -> Dict[str, Tuple]:
+def layer_param_axes(cfg: ModelConfig, model_size: Optional[int] = None,
+                     *, dense: bool = False) -> Dict[str, Tuple]:
     tp_attn = model_size is None or (cfg.n_heads % model_size == 0
                                      and cfg.n_kv_heads % model_size == 0)
     qax = "qdim" if tp_attn else None
@@ -98,18 +130,27 @@ def layer_param_axes(cfg: ModelConfig, model_size: Optional[int] = None) -> Dict
     ax: Dict[str, Tuple] = {
         "attn_norm": (None, None),
         "wq": (None, "fsdp", qax),
-        "wk": (None, "fsdp", kvax),
-        "wv": (None, "fsdp", kvax),
         "wo": (None, qax, "fsdp"),
         "mlp_norm": (None, None),
     }
-    if cfg.moe is not None:
+    if cfg.mla:  # the latent is shared by every head: not tensor-parallel
+        ax.update({"wkv_a": (None, "fsdp", None), "kv_norm": (None, None),
+                   "wkv_b": (None, None, qax)})
+    else:
+        ax.update({"wk": (None, "fsdp", kvax), "wv": (None, "fsdp", kvax)})
+    if cfg.moe is not None and not dense:
         ax.update({
             "router": (None, "fsdp", None),
             "w_gate": (None, "expert", "fsdp", None),
             "w_up": (None, "expert", "fsdp", None),
             "w_down": (None, "expert", None, "fsdp"),
         })
+        if cfg.moe.scoring == "sigmoid":
+            ax["router_bias"] = (None, None)
+        if cfg.moe.shared_d_ff:
+            ax.update({"shared_gate": (None, "fsdp", "ffn"),
+                       "shared_up": (None, "fsdp", "ffn"),
+                       "shared_down": (None, "ffn", "fsdp")})
     else:
         ax.update({
             "w_gate": (None, "fsdp", "ffn"),
@@ -137,6 +178,8 @@ def param_logical_axes(cfg: ModelConfig, model_size: Optional[int] = None) -> Di
         "layers": layer_param_axes(cfg, model_size),
         "final_norm": (None,),
     }
+    if cfg.n_dense_layers:
+        out["dense_layers"] = layer_param_axes(cfg, model_size, dense=True)
     if not cfg.tie_embeddings:
         out["lm_head"] = ("fsdp", "vocab")
     return out
@@ -147,6 +190,8 @@ def param_logical_axes(cfg: ModelConfig, model_size: Optional[int] = None) -> Di
 # ---------------------------------------------------------------------------
 
 def _qkv(x, lp, cfg: ModelConfig, positions):
+    if cfg.mla:
+        return _mla_qkv(x, lp, cfg, positions)
     B, S, _ = x.shape
     hd, KV = cfg.resolved_head_dim, cfg.n_kv_heads
     G = cfg.n_heads // KV
@@ -157,6 +202,27 @@ def _qkv(x, lp, cfg: ModelConfig, positions):
                    ).reshape(B, S, KV, G, hd)
     k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
+
+
+def _mla_qkv(x, lp, cfg: ModelConfig, positions):
+    """Latent attention's projections (DeepSeek-V2/V3, no q LoRA): q = x·W_q
+    in heads of nope + rope dims; ``[c_kv, k_pe] = x·W_kv_a``, RMSNorm on the
+    latent ``c_kv``, ``[k_nope, v] = c_kv·W_kv_b``; ``k_pe`` is one rope head
+    shared by all heads.  Keys are materialised as ``[k_nope, k_pe]`` so the
+    call goes through ``attention()`` with KV = heads, G = 1."""
+    B, S, _ = x.shape
+    H, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    r = cfg.kv_lora_rank
+    q = (x @ lp["wq"]).reshape(B, S, H, dn + dr)
+    q = jnp.concatenate(
+        [q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta)], -1)
+    kv_a = x @ lp["wkv_a"]
+    c_kv = rms_norm(kv_a[..., :r], lp["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta)
+    kv = (c_kv @ lp["wkv_b"]).reshape(B, S, H, dn + cfg.v_head_dim)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_pe, (B, S, H, dr))],
+                        -1)
+    return q.reshape(B, S, H, 1, dn + dr), k, kv[..., dn:]
 
 
 def attn_block(x, lp, cfg: ModelConfig, positions, *, attn_args: Dict[str, Any]):
@@ -185,20 +251,26 @@ def attn_block(x, lp, cfg: ModelConfig, positions, *, attn_args: Dict[str, Any])
     o = attn_lib.attention(q, k, v, causal=True, window=cfg.swa_window, **args)
     if sp:
         o = logical_constraint(o, ("batch", "attn_seq", None, None, None))
-    o = o.reshape(B, S, cfg.q_dim) @ lp["wo"]
+    o = o.reshape(B, S, cfg.attn_out_dim) @ lp["wo"]
     return o, (k, v)
 
 
 def mlp_block(x, lp, cfg: ModelConfig):
-    """Pre-norm FFN/MoE residual branch; returns (delta, aux_loss)."""
+    """Pre-norm FFN/MoE residual branch; returns (delta, aux_loss, load):
+    ``load`` (held experts,) is the tokens x picks routed to each expert the
+    layer holds, None where the layer has no held-expert routing."""
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
-        return moe_lib.moe_block(h, lp, cfg.moe)
+    if "router" in lp:
+        if cfg.moe.scoring == "sigmoid":
+            out, load = moe_lib.held_expert_block(h, lp, cfg.moe)
+            return out, jnp.float32(0), load
+        return moe_lib.moe_block(h, lp, cfg.moe) + (None,)
     if cfg.mlp_act == "swiglu":
         from repro.models.mlp import swiglu
-        return swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), jnp.float32(0)
+        return (swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]),
+                jnp.float32(0), None)
     from repro.models.mlp import gelu_mlp
-    return gelu_mlp(h, lp["w_up"], lp["w_down"]), jnp.float32(0)
+    return gelu_mlp(h, lp["w_up"], lp["w_down"]), jnp.float32(0), None
 
 
 def decoder_block(x, lp, cfg: ModelConfig, positions, *, ssm_state=None,
@@ -210,9 +282,19 @@ def decoder_block(x, lp, cfg: ModelConfig, positions, *, ssm_state=None,
         m_out, new_ssm = ssm_lib.mamba_head(h, lp, cfg, state=ssm_state)
         a_out = (a_out + m_out) * 0.5
     x = x + a_out
-    m, aux = mlp_block(x, lp, cfg)
+    m, aux, load = mlp_block(x, lp, cfg)
     x = shard_batch(x + m)
-    return x, kv, new_ssm, aux
+    return x, kv, new_ssm, aux, load
+
+
+def _compute_dtype(lp, cfg: ModelConfig):
+    """A layer's params as the body computes with them: floats in
+    ``cfg.dtype``, but a sigmoid-scored router's in float32."""
+    keep = (moe_lib.SIGMOID_FLOAT32_LEAVES if cfg.moe is not None
+            and cfg.moe.scoring == "sigmoid" else ())
+    return {k: (a.astype(cfg.dtype) if k not in keep
+                and jnp.issubdtype(a.dtype, jnp.floating) else a)
+            for k, a in lp.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -249,9 +331,11 @@ def scan_layers(body, x, layers, plan=None):
 def forward(params, cfg: ModelConfig, tokens, *, remat: str = "none",
             collect_cache: bool = False, cache_window: int = 0,
             attn_args: Optional[Dict[str, Any]] = None, plan=None):
-    """tokens: (B, S) int32 -> (logits, aux).
+    """tokens: (B, S) int32 -> (logits, aux, ys).
 
-    With ``collect_cache`` also returns the per-layer KV/SSM state for decode.
+    ``ys`` holds the scan's per-layer outputs: with ``collect_cache`` the
+    KV/SSM state for decode; in a held-expert layer ``expert_load``, the
+    ``(n_layers, held experts)`` tokens x picks routed to each held expert.
     ``plan`` (a :class:`~repro.core.partition.SegmentPlan`, static per jit)
     segments the layer scan for per-layer backward-FLOP elimination.
     """
@@ -267,11 +351,12 @@ def forward(params, cfg: ModelConfig, tokens, *, remat: str = "none",
                     jnp.zeros((B, cfg.ssm.conv_width - 1, di), cfg.dtype))
 
     def body(x, lp):
-        lp = jax.tree.map(lambda a: a.astype(cfg.dtype)
-                          if jnp.issubdtype(a.dtype, jnp.floating) else a, lp)
-        x, kv, new_ssm, aux = decoder_block(
+        lp = _compute_dtype(lp, cfg)
+        x, kv, new_ssm, aux, load = decoder_block(
             x, lp, cfg, positions, ssm_state=init_ssm, attn_args=attn_args)
         ys = {"aux": aux}
+        if load is not None:
+            ys["expert_load"] = load
         if collect_cache:
             k, v = kv
             if cache_window and cache_window < S:
@@ -287,13 +372,19 @@ def forward(params, cfg: ModelConfig, tokens, *, remat: str = "none",
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.checkpoint_dots_no_batch_dims)
 
+    if "dense_layers" in params:
+        if collect_cache:
+            raise NotImplementedError("no decode cache for leading dense layers")
+        # the leading dense layers: the same block and remat, before the
+        # stacked scan; the segment plan covers the scan's layers only
+        x, _ = scan_layers(body, x, params["dense_layers"])
     x, ys = scan_layers(body, x, params["layers"], plan)
     x = rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"]).astype(cfg.dtype)
     logits = x @ head
     logits = logical_constraint(logits, ("batch", None, "vocab"))
     aux = ys.pop("aux").mean()
-    return (logits, aux, ys) if collect_cache else (logits, aux)
+    return logits, aux, ys
 
 
 # ---------------------------------------------------------------------------
@@ -358,9 +449,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
         xs["ssm_h"], xs["ssm_conv"] = cache["ssm_h"], cache["ssm_conv"]
 
     def body(x, layer_in):
-        lp = jax.tree.map(lambda a: a.astype(cfg.dtype)
-                          if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                          layer_in["lp"])
+        lp = _compute_dtype(layer_in["lp"], cfg)
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q, k_new, v_new = _qkv(h, lp, cfg, positions)
         kc = jax.lax.dynamic_update_slice_in_dim(layer_in["k"], k_new, slot, axis=1)
@@ -375,7 +464,7 @@ def decode_step(params, cfg: ModelConfig, cache, tokens):
             a_out = (a_out + m_out) * 0.5
             ys["ssm_h"], ys["ssm_conv"] = h2, conv2
         x = x + a_out
-        m, _ = mlp_block(x, lp, cfg)
+        m = mlp_block(x, lp, cfg)[0]
         return x + m, ys
 
     x, ys = jax.lax.scan(body, x, xs)
@@ -526,9 +615,7 @@ def decode_step_paged(params, cfg: ModelConfig, pool, tokens, *, active=None,
         xs["ssm_h"], xs["ssm_conv"] = pool["ssm_h"], pool["ssm_conv"]
 
     def body(x, layer_in):
-        lp = jax.tree.map(lambda a: a.astype(cfg.dtype)
-                          if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                          layer_in["lp"])
+        lp = _compute_dtype(layer_in["lp"], cfg)
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q, k_new, v_new = _qkv(h, lp, cfg, positions)
         kp = layer_in["k"].at[phys, off].set(k_new[:, 0])
@@ -548,7 +635,7 @@ def decode_step_paged(params, cfg: ModelConfig, pool, tokens, *, active=None,
             a_out = (a_out + m_out) * 0.5
             ys["ssm_h"], ys["ssm_conv"] = h2, conv2
         x = x + a_out
-        m, _ = mlp_block(x, lp, cfg)
+        m = mlp_block(x, lp, cfg)[0]
         return x + m, ys
 
     def _gather(pages):

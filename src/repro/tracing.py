@@ -44,3 +44,18 @@ class span:
             _open.names.pop()
             jax.monitoring.record_event_time_span(
                 self.name, self._t0, t1, parent=self._parent, **self.attrs)
+
+
+def mark(name: str, **attrs: int) -> None:
+    """An instant host span whose arguments the trace event's name keeps:
+    ``name#k=v,k=v``.  A span's keyword arguments reach the profiler trace
+    as event stats, which a reader of event names alone loses; left
+    unclosed by ``#``, the name is kept whole.  ``jax.monitoring``
+    listeners get ``name`` and the arguments as keywords, as from
+    :class:`span`."""
+    stack = getattr(_open, "names", None)
+    label = name + "#" + ",".join(f"{k}={v}" for k, v in attrs.items())
+    with jax.profiler.TraceAnnotation(label):
+        t = time.time()
+    jax.monitoring.record_event_time_span(
+        name, t, t, parent=stack[-1] if stack else "", **attrs)

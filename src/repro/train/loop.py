@@ -85,7 +85,7 @@ from repro.optim.optimizer import (align_moments, align_packed_tree,
                                    expand_packed_tree_host)
 from repro.robustness.faults import FaultyBatchSource, tag_grad_faults
 from repro.robustness.harness import FaultActuator, GracefulShutdown
-from repro.tracing import span
+from repro.tracing import mark, span
 from repro.train.state import (TrainState, init_train_state,
                                steps_completed)
 from repro.train.step import make_eval_step, make_multi_step
@@ -550,6 +550,15 @@ class Trainer:
             if tier2_on and float(np.max(np.asarray(m["all_frozen"],
                                                     np.float64))) >= 1.0:
                 tier2 = True
+            if "expert_assigned" in m:
+                ran = executed >= 1.0
+                load = {k: np.asarray(m[f"expert_{k}"], np.float64)[ran]
+                        for k in ("assigned", "assigned_live", "busiest")}
+                # the block's held-expert work, for the trace
+                mark("/repro/train/expert_load", step=inflight.start,
+                     assigned=int(load["assigned"].sum()),
+                     assigned_live=int(load["assigned_live"].sum()),
+                     busiest=int(load["busiest"].max(initial=0)))
             if self.progress_cb is not None:
                 self.progress_cb(inflight.start + inflight.size, ema_dt)
             return tier2

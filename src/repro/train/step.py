@@ -41,6 +41,30 @@ from repro.models import model
 from repro.optim.optimizer import apply_updates, global_norm, lr_at
 
 
+#: per-step metrics that count work over the whole batch: summed, not
+#: averaged, over microbatches and data-parallel shards
+SUMMED_METRICS = ("expert_load",)
+
+
+def _combine(metrics, mean, total):
+    return {k: (total(v) if k in SUMMED_METRICS else mean(v))
+            for k, v in metrics.items()}
+
+
+def expert_load_metrics(load, spec: MonitorSpec, frozen) -> Dict[str, Any]:
+    """One step's held-expert counts from the model's ``expert_load`` (L, E):
+    tokens x picks routed to held experts, those routed to live rows (a
+    ``(layer, expert)`` row is live unless every routed-expert matrix froze
+    it), and the busiest row's."""
+    rows = [frozen[n] for n, (_, gran) in spec.groups.items()
+            if gran == 2 and frozen[n].shape == load.shape]
+    dead = jnp.all(jnp.stack(rows), axis=0) if rows else False
+    load = load.astype(jnp.float32)
+    return {"expert_assigned": load.sum(),
+            "expert_assigned_live": jnp.where(dead, 0.0, load).sum(),
+            "expert_busiest": load.max()}
+
+
 def _loss(params, base_params, batch, cfg: ModelConfig, tcfg: TrainConfig,
           attn_args=None, plan=None):
     if tcfg.lora is not None:
@@ -140,7 +164,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
                                 params)
             (grads, loss), metrics = jax.lax.scan(acc, (zero, 0.0), split)
             grads = jax.tree.map(lambda g: g / n, grads)
-            return loss / n, jax.tree.map(lambda m: m.mean(), metrics), grads
+            return loss / n, _combine(metrics, lambda m: m.mean(0),
+                                      lambda m: m.sum(0)), grads
         return grads_of(params, base_params, batch)
 
     if dp_axes is not None:
@@ -161,8 +186,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
                                                    batch, mb_local)
             grads = reduce_gradients(grads, dp_axes, reduce_plan)
             loss = jax.lax.pmean(loss, dp_axes)
-            metrics = jax.tree.map(lambda m: jax.lax.pmean(m, dp_axes),
-                                   metrics)
+            metrics = _combine(metrics, lambda m: jax.lax.pmean(m, dp_axes),
+                               lambda m: jax.lax.psum(m, dp_axes))
             return loss, metrics, grads
 
         _sharded = jax.shard_map(_reduce_body, mesh=dp_mesh,
@@ -230,6 +255,10 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
                 spec=spec, group_frozen=frozen, backend=backend,
                 param_specs=pspecs)
         metrics = dict(metrics)
+        load = metrics.pop("expert_load", None)
+        if load is not None:
+            metrics.update(expert_load_metrics(load, spec,
+                                               state.grades.frozen))
         metrics["grad_norm"] = global_norm(grads)
         metrics["frozen_frac"] = frozen_fraction(frozen)
         metrics["all_frozen"] = all_frozen(frozen)

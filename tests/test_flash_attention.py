@@ -69,6 +69,41 @@ def test_flash_fwd_and_grads_match_oracle(S, T, KV, G, hd, causal, window,
                                    atol=2e-4, err_msg=name)
 
 
+#           S   T  KV  hd   hdv  causal kv_valid
+MLA_CASES = [
+    (64, 64, 2, 192, 128, True,  False),   # latent attention: q·k 192, P·V 128
+    (45, 61, 1, 192, 128, True,  True),    # ragged S/T + kv_valid padding
+    (64, 64, 2, 192, 128, False, True),    # bidirectional + kv_valid
+]
+
+
+@pytest.mark.parametrize("S,T,KV,hd,hdv,causal,kv_valid", MLA_CASES)
+def test_flash_two_head_dims_match_oracle(S, T, KV, hd, hdv, causal,
+                                          kv_valid):
+    """q·k over ``hd`` and P·V over ``hdv``: output and all three gradients
+    equal ``full_attention``'s, scaled by ``hd ** -0.5``."""
+    q, k, _, valid = _inputs(S, T, KV, 1, hd, kv_valid)
+    v = jax.random.normal(jax.random.PRNGKey(7), (1, T, KV, hdv))
+    w = jax.random.normal(jax.random.PRNGKey(9), (1, S, KV, 1, hdv))
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v) * w)
+
+    flash = functools.partial(flash_attention, causal=causal, kv_valid=valid,
+                              block_q=BQ, block_k=BK)
+    oracle = functools.partial(full_attention, causal=causal, kv_valid=valid)
+    lf, gf = jax.value_and_grad(functools.partial(loss, flash),
+                                (0, 1, 2))(q, k, v)
+    lo, go = jax.value_and_grad(functools.partial(loss, oracle),
+                                (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(lf), np.asarray(lo), rtol=2e-5,
+                               atol=2e-4)
+    for a, b, name in zip(gf, go, ("dq", "dk", "dv")):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+
+
 def test_flash_bf16_forward():
     q, k, v, _ = _inputs(64, 64, 2, 2, 32, False, dtype=jnp.bfloat16)
     got = flash_attention(q, k, v, causal=True, block_q=BQ, block_k=BK)

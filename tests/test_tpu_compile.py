@@ -109,6 +109,41 @@ def test_flash_fwd_bwd_compiles(batch, masked, one_chip):
     assert bwd == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
 
 
+def test_latent_attention_and_expert_matmuls_compile(one_chip):
+    """Moonlight-16B-A3B's widths: flash with q·k over 192 and P·V over 128
+    (16 heads, G = 1), and the held experts' grouped matmuls, fwd + bwd."""
+    from repro.config import MoEConfig
+    from repro.models import moe
+    S, H = 512, 16
+    shapes = [((1, S, H, 1, 192), jnp.bfloat16), ((1, S, H, 192), jnp.bfloat16),
+              ((1, S, H, 128), jnp.bfloat16)]
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    found = compile_kernels(jax.grad(loss, argnums=(0, 1, 2)), shapes,
+                            one_chip)
+    assert found == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+    cfg = MoEConfig(n_experts=64, top_k=6, d_ff=1408, scoring="sigmoid",
+                    n_held=8)
+
+    def expert_loss(x, router, bias, wg, wu, wd):
+        p = dict(router=router, router_bias=bias, w_gate=wg, w_up=wu,
+                 w_down=wd)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe, "resolve_interpret", lambda _: False)
+            out, _ = moe.held_expert_block(x, p, cfg)
+        return out.astype(jnp.float32).sum()
+
+    found = compile_kernels(jax.grad(expert_loss, argnums=(0, 3, 4, 5)), [
+        ((1, S, D * 2), jnp.bfloat16), ((D * 2, 64), jnp.float32),
+        ((64,), jnp.float32), ((8, D * 2, 1408), jnp.bfloat16),
+        ((8, D * 2, 1408), jnp.bfloat16), ((8, 1408, D * 2), jnp.bfloat16)],
+        one_chip)
+    assert found["gmm"] == 6 and found["tgmm"] == 3, found
+
+
 @pytest.mark.parametrize("kv,g,hd,page_size,pages_per_split,budget", [
     (KV, G, HD, 8, 0, 160),     # qwen3-0.6b, 160-token budget per slot
     (KV, G, HD, 16, 4, 160),    # qwen3-0.6b, several splits per sequence

@@ -13,7 +13,7 @@ from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
 from repro.data.pipeline import Prefetcher, make_batches, stack_batches
 from repro.robustness.faults import FaultPlan, FaultyBatchSource
-from repro.tracing import span
+from repro.tracing import mark, span
 from repro.train.loop import Trainer
 from repro.train.state import init_train_state
 from repro.train.step import make_multi_step
@@ -213,3 +213,20 @@ def test_named_scopes_in_the_lowered_step():
         state, block).as_text(debug_info=True)
     for scope in ("fwd_bwd", "ef_compress", "grades_monitor", "optimizer"):
         assert f"/{scope}/" in hlo, scope
+
+
+def test_a_mark_keeps_its_arguments_in_the_trace_events_name(tmp_path):
+    """The profiler decodes a span's keyword arguments into event stats; a
+    mark's stay in the event's name, and listeners get them as keywords."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with Recorder() as rec, span(T + "drain", step=8):
+            mark(T + "expert_load", step=8, assigned=12, assigned_live=5)
+    finally:
+        jax.profiler.stop_trace()
+    names = [e[0] for e in _trace_events(str(tmp_path))]
+    assert T + "expert_load#step=8,assigned=12,assigned_live=5" in names
+    assert T + "drain" in names
+    (got,) = rec.named(T + "expert_load")
+    assert got[1] == got[2] and got[3] == {
+        "parent": T + "drain", "step": 8, "assigned": 12, "assigned_live": 5}

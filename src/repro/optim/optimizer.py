@@ -13,8 +13,9 @@ Three masking tiers compose here (DESIGN.md §2):
   only the live rows — ``(n_live,) + trailing`` — so frozen (layer, expert)
   rows free their 8 bytes/param *before* the whole type converges.  The update
   gathers live rows with static indices (compile-time slices), runs the fused
-  or jnp update on the packed arrays, and scatters params back; frozen rows
-  stay bit-identical.  ``align_moments`` re-packs m/v at sync boundaries and
+  or jnp update on the packed arrays, and writes params back in place, one
+  static ``dynamic_update_slice`` per run of live rows; frozen rows stay
+  bit-identical.  ``align_moments`` re-packs m/v at sync boundaries and
   after checkpoint restore (packing is a pure function of the boundary masks,
   so a resumed run re-derives the identical layout).
 
@@ -54,6 +55,26 @@ def _is_row_mask(t) -> bool:
 def _live_rows(t: "np.ndarray") -> "np.ndarray":
     """Static indices of the live rows in the collapsed granularity axis."""
     return np.nonzero(np.asarray(t, bool).reshape(-1))[0]
+
+
+def _live_runs(t: "np.ndarray") -> list:
+    """Static ``(start, stop)`` runs of consecutive live rows in the collapsed
+    granularity axis, in order (Python ints)."""
+    idx = _live_rows(t)
+    if idx.size == 0:
+        return []
+    cuts = np.flatnonzero(np.diff(idx) != 1) + 1
+    return [(int(r[0]), int(r[-1]) + 1) for r in np.split(idx, cuts)]
+
+
+def packed_row_counts(trainable) -> Dict[str, int]:
+    """How much of ``trainable`` takes the Tier-1.5 packed-row path: the
+    row-packed leaves, their live rows, and the in-place row writes
+    (one per run of live rows) the update makes."""
+    masks = [t for t in jax.tree.leaves(trainable) if _is_row_mask(t)]
+    return {"leaves": len(masks),
+            "rows": sum(int(np.asarray(t, bool).sum()) for t in masks),
+            "runs": sum(len(_live_runs(t)) for t in masks)}
 
 
 def moment_shape(p, t):
@@ -120,7 +141,7 @@ def apply_updates(params, grads, opt: OptState, tcfg: TrainConfig, *,
     A ``trainable`` leaf that is a boolean row mask (Tier 1.5) routes through
     the packed-row path: live rows are gathered with *static* indices, the
     packed m/v (``(n_live,) + trailing``) are updated — through the same fused
-    kernel when eligible — and only the live rows of ``p`` are scattered back,
+    kernel when eligible — and only the live rows of ``p`` are written back,
     so frozen rows stay bit-identical without streaming their moments.
     """
     from repro.core.grades import _key_path, broadcast_mask
@@ -216,9 +237,13 @@ def _packed_row_update(p, g, m, v, row_mask, flags, lr, count,
     """Tier-1.5 update for one leaf whose moments hold live rows only.
 
     ``row_mask`` is the host boolean live-row mask (granularity shape); its
-    nonzero indices are compile-time constants, so the gathers/scatter lower
-    to static slices.  ``flags`` (the group's *dynamic* freeze array) still
-    masks rows that froze since the last boundary.
+    nonzero indices are compile-time constants, so the gathers lower to
+    static slices, and the updated rows go back with one
+    ``dynamic_update_slice`` at a constant start per run of live rows, which
+    XLA applies in place to the (donated) stacked buffer.  A scatter there
+    lowers, on the TPU, to a loop over the rows that copies the whole stack
+    in and out of its carry on every trip.  ``flags`` (the group's *dynamic*
+    freeze array) still masks rows that froze since the last boundary.
     """
     from repro.core.grades import broadcast_mask
     from repro.kernels import dispatch as _dispatch
@@ -249,8 +274,12 @@ def _packed_row_update(p, g, m, v, row_mask, flags, lr, count,
     else:
         pn_live, mn, vn = upd(p_live, g_live, m, v,
                               broadcast_mask(fl_live, p_live), True)
-    pn = pc.at[live_idx].set(pn_live).reshape(p.shape)
-    return pn, mn, vn
+    off = 0
+    for start, stop in _live_runs(row_mask):
+        pc = jax.lax.dynamic_update_slice_in_dim(
+            pc, pn_live[off:off + stop - start], start, axis=0)
+        off += stop - start
+    return pc.reshape(p.shape), mn, vn
 
 
 def align_packed_tree(tree, params, dtype, trainable, old_trainable=None):

@@ -38,7 +38,9 @@ from repro.distributed.sharding import (ShardingRules, mesh_axis_size,
                                         model_axis_size)
 from repro.kernels.dispatch import KernelBackend, resolve_backend
 from repro.models import model
-from repro.optim.optimizer import apply_updates, global_norm, lr_at
+from repro.optim.optimizer import (apply_updates, global_norm, lr_at,
+                                   packed_row_counts)
+from repro.tracing import mark
 
 
 #: per-step metrics that count work over the whole batch: summed, not
@@ -298,6 +300,10 @@ def make_multi_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
     pure pass-throughs and the final state is bit-identical to
     ``sync_interval=1``.  The same factory serves K=1, so both paths run the
     identical scan-body HLO.
+
+    Each trace marks ``/repro/train/packed_rows`` once with the step's
+    Tier-1.5 layout (``optimizer.packed_row_counts``): the row-packed
+    leaves, their live rows, and the in-place row writes they compile to.
     """
     single = make_train_step(cfg, tcfg, spec, static_frozen, backend=backend,
                              param_specs=param_specs, plan=plan,
@@ -305,6 +311,9 @@ def make_multi_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
     tier2 = tcfg.grades.enabled and bool(spec.groups)
 
     def multi_step(state, block):
+        mark("/repro/train/packed_rows", **packed_row_counts(trainable_mask(
+            state.params, spec, static_frozen, row_frozen)))
+
         def run(state, batch):
             new_state, m = single(state, batch)
             return new_state, dict(m, executed=jnp.float32(1))

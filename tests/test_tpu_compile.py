@@ -16,10 +16,13 @@ other workers never try.  Compiles run in this process with JAX's persistent
 compilation cache off (``conftest.py`` turns it off for every test).
 """
 import contextlib
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
@@ -27,12 +30,15 @@ from jax.sharding import PartitionSpec as P
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
+from repro.core.partition import (fully_frozen_types, plan_row_masks,
+                                  segment_plan, trainable_mask)
 from repro.distributed import explicit_reduce_axes, make_mesh, use_mesh
 from repro.kernels import compiled_kernels, ops
 from repro.kernels.decode_attention import paged_decode_attention
 from repro.kernels.dispatch import KernelBackend
 from repro.kernels.flash_attention import flash_attention
 from repro.launch.mesh import rules_for
+from repro.optim.optimizer import init_opt_state
 from repro.train.state import init_train_state
 from repro.train.step import make_multi_step
 
@@ -207,3 +213,68 @@ def test_train_block_compiles_and_fits(chips, topo, one_chip):
     assert found["grades_norm"] == found["masked_adamw"] == n_leaves, found
     assert found["flash_dq"] == found["flash_dkv"] == 1, found
     assert found["flash_fwd"] >= 1, found
+
+
+def test_packed_rows_are_written_back_in_place(one_chip):
+    """Tier 1.5 at qwen3-0.6b's widths, in the trainer's sync block: the MLP
+    of the lowest 16 layers frozen (one run of live rows in each leaf) and
+    wq frozen in layers 8-15 (two runs), moments packed to the live rows.
+    The update writes each run back into the donated stack in place, so no
+    computation of the compiled block's steps copies a whole packed leaf.  (A
+    scatter there lowers to a loop over the live rows that copies the stack
+    in and out of its carry on every trip.)  The block's entry may still
+    change a leaf's layout, once per block: a packed ``wq`` goes through the
+    scan in the layout its matmuls read."""
+    cfg = configs.get("qwen3-0.6b")
+    tcfg = TrainConfig(seq_len=128, global_batch=4, sync_interval=2,
+                       remat="full", grades=GradESConfig(enabled=True))
+    params = jax.eval_shape(lambda k: init_train_state(k, cfg, tcfg).params,
+                            jax.random.PRNGKey(0))
+    spec = build_monitor_spec(params)
+    frozen = {n: np.zeros(spec.mask_shape(params, n), bool)
+              for n in spec.groups}
+    for name in ("layers/w_gate", "layers/w_up", "layers/w_down"):
+        frozen[name][:16] = True
+    frozen["layers/wq"][8:16] = True
+    static = fully_frozen_types(frozen)
+    plan = segment_plan(frozen, spec, cfg.n_layers, tcfg.segment_max)
+    rows = plan_row_masks(plan, spec, frozen)
+    trainable = trainable_mask(params, spec, static, rows)
+
+    def init(key):
+        st = init_train_state(key, cfg, tcfg)
+        return dataclasses.replace(
+            st, opt=init_opt_state(st.params, tcfg, trainable))
+
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(init, jax.random.PRNGKey(0)))
+    block = {k: jax.ShapeDtypeStruct((2, 4, 128), jnp.int32,
+                                     sharding=one_chip)
+             for k in ("tokens", "labels")}
+    step = make_multi_step(cfg, tcfg, spec, static, backend=KernelBackend(
+        "pallas", interpret=False), plan=plan, row_frozen=rows)
+    marks = []
+
+    def listen(event, _t0, _t1, **attrs):
+        if event == "/repro/train/packed_rows":
+            marks.append(attrs)
+
+    jax.monitoring.register_event_time_span_listener(listen)
+    try:
+        text = jax.jit(step, donate_argnums=0).lower(state,
+                                                     block).compile().as_text()
+    finally:
+        jax.monitoring.unregister_event_time_span_listener(listen)
+    assert [(m["leaves"], m["rows"], m["runs"]) for m in marks] == [
+        (4, 56, 5)], marks
+    stacked = {"f32[{}]".format(",".join(map(str, p.shape)))
+               for p, t in zip(jax.tree.leaves(params),
+                               jax.tree.leaves(trainable))
+               if isinstance(t, np.ndarray)}
+    per_step = set()       # copies outside the block's entry computation
+    for comp in re.split(r"\n(?=\S)", text):
+        if not comp.startswith("ENTRY"):
+            per_step |= set(re.findall(r"(f32\[[\d,]+\])\{[^}]*\} copy\(",
+                                       comp))
+    assert len(stacked) == 3 and not stacked & per_step, stacked & per_step

@@ -169,7 +169,7 @@ def check_train_step_kernels(cfg, tcfg, tag: str):
     K, B, S = tcfg.sync_interval, tcfg.global_batch, tcfg.seq_len
     block = {k: jax.ShapeDtypeStruct((K, B, S), jnp.int32)
              for k in ("tokens", "labels")}
-    fn = jax.jit(make_multi_step(cfg, tcfg, spec, frozenset(),
+    fn = jax.jit(make_multi_step(cfg, tcfg, spec,
                                  backend=resolve_backend(tcfg.kernels)),
                  donate_argnums=0)
     t0 = time.perf_counter()

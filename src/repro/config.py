@@ -255,8 +255,6 @@ class GradESConfig:
     # Per-component tau overrides, keyed by matrix-type name (paper Table 10 uses
     # modality-specific thresholds; we generalize to per-type).
     tau_overrides: Mapping[str, float] = field(default_factory=dict)
-    # Tier-1: re-jit with stop_gradient once a whole matrix type is frozen.
-    static_repartition: bool = True
     # Normalize the L1 norm by element count (makes tau transferable across sizes).
     normalize: bool = True
 

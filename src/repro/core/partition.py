@@ -32,14 +32,16 @@ intersection, so the plan changes at most once per (cell, type):
 
 versus ~L · n_types for a planner that chases every per-layer freeze.
 
-``static_frozen`` (whole-type) is carried as a frozenset of group names and
-the plan as a hashable :class:`SegmentPlan`; both are *static* per compiled
-step — each distinct pair is a distinct compiled executable.
+Everything the host derives from one boundary's masks travels as one
+:class:`FreezePlan` (:func:`freeze_plan`): the whole-type set, the segment
+plan, the packed-row masks, the :class:`ReducePlan` and the moment layout
+``trainable``.  It is *static* per compiled step, and it compares equal
+exactly when the compiled step would be the same.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import AbstractSet, Dict, FrozenSet, List, Optional, Tuple
+from dataclasses import dataclass, field
+from typing import AbstractSet, Any, Dict, FrozenSet, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -94,12 +96,10 @@ def trainable_mask(params, spec: MonitorSpec,
       free per layer-row rather than per expert (see :func:`plan_signature`).
 
     ``row_frozen`` should be the **plan-quantized** masks from
-    :func:`plan_row_masks` (what the trainer passes), NOT the raw
-    ``device_get(state.grades.frozen)`` — raw masks would change the moment
-    layout on every per-layer freeze, defeating the plan's
-    ``segment_max · n_types`` recompile bound.  None keeps the legacy
-    whole-type behavior (also used under multi-device meshes, where packed
-    rows would break the divisibility of the moment shardings).
+    :func:`plan_row_masks` (as :func:`freeze_plan` passes them), not the raw
+    device masks, which would change the moment layout on every per-layer
+    freeze.  None keeps the whole-type behavior (multi-device meshes, where
+    packed rows would break the divisibility of the moment shardings).
     """
     # imported here: repro.models reaches back into repro.core
     from repro.models.moe import BUFFERS
@@ -274,8 +274,8 @@ class ReducePlan:
       pass through as exact zeros.
 
     Hashable and comparable like :class:`SegmentPlan`; it is a pure function
-    of ``(static_frozen, plan)``, so the trainer's existing Tier-1 recompile
-    comparison covers it and the ``segment_max · n_types`` bound still holds.
+    of ``(static_frozen, plan)``, so a :class:`FreezePlan`'s comparison covers
+    it and the ``segment_max · n_types`` bound still holds.
     """
 
     entries: Tuple[Tuple[Tuple[str, ...],
@@ -336,6 +336,44 @@ def gradient_reduce_plan(spec: MonitorSpec,
         merged = _merge_ranges(live)
         entries.extend((p, merged) for p in sorted(paths))
     return ReducePlan(entries=tuple(sorted(entries)))
+
+
+# ---------------------------------------------------------------------------
+# The freeze plan: every artifact of one boundary's masks, as one value
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class FreezePlan:
+    """What the host derives from one boundary's frozen masks, and what a
+    compiled step is built for (DESIGN.md §2): the Tier-1 ``whole``-type set,
+    the Tier-1.5 ``segments`` (None for a family whose forward consumes
+    none), the packed-row masks ``rows`` (None where moments are not
+    row-packed), the ``reduce`` plan and the moment layout ``trainable``.
+
+    Equality and hash cover ``whole`` and ``segments`` only: the other three
+    are pure functions of those two (and of the run's fixed switches), so two
+    plans compare equal exactly when they compile to the same step."""
+
+    whole: FrozenSet[str]
+    segments: Optional[SegmentPlan]
+    rows: Optional[Dict[str, "np.ndarray"]] = field(compare=False)
+    reduce: ReducePlan = field(compare=False)
+    trainable: Any = field(compare=False)
+
+
+def freeze_plan(frozen_host: Dict[str, "np.ndarray"], params,
+                spec: MonitorSpec, n_layers: int, segment_max: int, *,
+                segments: bool = True, pack_rows: bool = True) -> FreezePlan:
+    """The :class:`FreezePlan` of the host masks ``frozen_host`` for the
+    tree ``params`` (arrays or ShapeDtypeStructs).  ``segments`` False
+    derives no segment plan; ``pack_rows`` False keeps moments unpacked."""
+    whole = fully_frozen_types(frozen_host)
+    plan = (segment_plan(frozen_host, spec, n_layers, segment_max)
+            if segments else None)
+    rows = plan_row_masks(plan, spec, frozen_host) if pack_rows else None
+    return FreezePlan(whole=whole, segments=plan, rows=rows,
+                      reduce=gradient_reduce_plan(spec, whole, plan, n_layers),
+                      trainable=trainable_mask(params, spec, whole, rows))
 
 
 def reduce_live_elements(tree, rplan: Optional[ReducePlan]) -> int:

@@ -10,22 +10,20 @@ bookkeeping overlaps device execution:
 
 * Tier 0 (in-jit freeze masks) lives in the compiled step.
 * Tier 1 / 1.5: at boundaries aligned to ``round_up(repartition_interval,
-  K)`` the host reads the (tiny) frozen masks and derives three static
-  artifacts — the whole-type ``static_frozen`` set, the per-layer
-  :class:`~repro.core.partition.SegmentPlan` (the layer scan is re-jit as a
-  chain of segment scans whose signatures' dW einsums XLA never builds), and
-  the per-row ``row_frozen`` masks that pack optimizer moments to live rows
-  (``optim.optimizer.align_moments`` repacks the live state before the
-  re-jit).  All three are pure functions of the masks, so a resumed run
-  re-derives them identically; recompiles are bounded at
-  ``segment_max · n_types`` by the planner's grid quantization
-  (DESIGN.md §2).  Runs with different ``sync_interval`` are bit-identical
-  when they resolve to the same aligned interval (``repartition_interval`` a
+  K)`` the host reads the (tiny) frozen masks and derives one
+  :class:`~repro.core.partition.FreezePlan` from them
+  (``partition.freeze_plan``); when it differs from the step's plan,
+  ``repartition`` repacks the moments and error-feedback buffers to its
+  layout and re-jits.  The plan is a pure function of the masks, so a
+  resumed run re-derives it identically; recompiles are bounded at
+  ``segment_max · n_types`` by the planner's grid quantization (DESIGN.md
+  §2).  Runs with different ``sync_interval`` are bit-identical when they
+  resolve to the same aligned interval (``repartition_interval`` a
   common multiple of the K values compared): the re-jit then lands on the
   same global step either way.  With a misaligned interval the re-jit shifts
   to the next K-boundary — still correct, but the stop_gradient changes the
   global-norm clip denominator, so the runs are no longer bit-comparable.
-  The artifacts also refresh at *checkpoint* boundaries (so a resume — which
+  The plan also refreshes at *checkpoint* boundaries (so a resume — which
   unavoidably applies the masks saved at the checkpoint step — re-derives
   exactly the uninterrupted run's state): the checkpoint cadence is thereby
   part of the numeric schedule, and runs are bit-comparable only when their
@@ -72,9 +70,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.config import ModelConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
-from repro.core.partition import (fully_frozen_types, gradient_reduce_plan,
-                                  plan_row_masks, segment_plan,
-                                  trainable_mask)
+from repro.core.partition import FreezePlan, freeze_plan
 from repro.data.pipeline import Prefetcher, make_batches
 from repro.distributed.sharding import active_mesh, active_rules
 from repro.kernels.dispatch import resolve_backend
@@ -297,10 +293,6 @@ class Trainer:
         # Kernel backend is resolved once per run (static across Tier-1
         # re-jits); per-group fused-vs-jnp selection happens inside the step.
         backend = resolve_backend(tcfg.kernels)
-        # Tier 1 / 1.5 static artifacts — all pure functions of the boundary
-        # frozen masks (resume re-derives them bit-identically):
-        use_plan = (tcfg.grades.enabled and tcfg.grades.static_repartition
-                    and supports_segment_plan(cfg))
         # Per-row moment packing changes moment shapes, which would break the
         # divisibility of the moment shardings derived from full param shapes
         # — keep it to single-device runs (the whole-type placeholder still
@@ -309,47 +301,44 @@ class Trainer:
         mesh = active_mesh()
         pack_rows = mesh is None or mesh.devices.size <= 1
 
-        def freeze_artifacts(frozen_host):
-            static = fully_frozen_types(frozen_host)
-            plan = (segment_plan(frozen_host, spec, cfg.n_layers,
-                                 tcfg.segment_max) if use_plan else None)
-            # Packing is keyed to the plan's (quantized, pure-in-the-masks)
-            # skip set, so the moment layout changes only when the plan does:
-            # the segment_max * n_types recompile bound covers repacking, and
-            # a resume re-derives the stored layout from the restored masks.
-            rows = plan_row_masks(plan, spec, frozen_host) if pack_rows \
-                else None
-            # The ReducePlan (freeze-aware explicit DP reduce, DESIGN.md §3)
-            # is pure in (static, plan), so the recompile comparison below
-            # covers it: whenever it changes, the Tier-1 re-jit was happening
-            # anyway.
-            rplan = gradient_reduce_plan(spec, static, plan, cfg.n_layers)
-            return static, plan, rows, rplan
+        def derive(st) -> FreezePlan:
+            """The freeze plan of ``st``'s frozen masks — a pure function of
+            them, so a resume re-derives it bit-identically."""
+            return freeze_plan(
+                jax.device_get(st.grades.frozen), st.params, spec,
+                cfg.n_layers, tcfg.segment_max,
+                segments=tcfg.grades.enabled and supports_segment_plan(cfg),
+                pack_rows=pack_rows)
 
-        static_frozen, plan, row_frozen, reduce_plan = freeze_artifacts(
-            jax.device_get(state.grades.frozen))
-        trainable = trainable_mask(state.params, spec, static_frozen,
-                                   row_frozen)
+        # Multiplicative LR backoff applied by the numerics guard: each
+        # rollback halves (by rollback_lr_backoff) the LR of the re-dispatched
+        # program.  Folded into the compiled step via a config replace, so the
+        # schedule stays a pure function of opt.count.
+        lr_scale = 1.0
+
+        def repartition(st, new: FreezePlan, old_trainable=None):
+            """``st`` with its moments and int8-EF buffers packed from
+            ``old_trainable``'s layout (None: as a checkpoint stores them)
+            to ``new``'s, and the step compiled for ``new``."""
+            opt = align_moments(st.opt, st.params, tcfg, new.trainable,
+                                old_trainable)
+            ef = (None if st.ef_error is None else align_packed_tree(
+                st.ef_error, st.params, jnp.float32, new.trainable,
+                old_trainable))
+            if opt is not st.opt or ef is not st.ef_error:
+                st = dataclasses.replace(st, opt=opt, ef_error=ef)
+            run_tcfg = (tcfg if lr_scale == 1.0 else
+                        dataclasses.replace(tcfg, lr=tcfg.lr * lr_scale))
+            return st, jax.jit(make_multi_step(cfg, run_tcfg, spec, new,
+                                               backend=backend),
+                               donate_argnums=0)
+
         # Checkpoints store moments in the plan-independent layout (full
         # buffers for any live rows, whole-type placeholders — see
         # _checkpoint_state), so a restored state packs down to whatever this
         # run's plan/segment_max implies, with no layout provenance needed.
-        new_opt = align_moments(state.opt, state.params, tcfg, trainable)
-        if new_opt is not state.opt:
-            state = dataclasses.replace(state, opt=new_opt)
-
-        def _align_ef(st, trainable_, old_trainable=None):
-            """Pack the int8-EF error buffers to the same layout the moments
-            follow (full / placeholder / live-rows) — compression skips frozen
-            leaves, so their buffers drop with them (DESIGN.md §4)."""
-            if st.ef_error is None:
-                return st
-            new_ef = align_packed_tree(st.ef_error, st.params, jnp.float32,
-                                       trainable_, old_trainable)
-            return (st if new_ef is st.ef_error
-                    else dataclasses.replace(st, ef_error=new_ef))
-
-        state = _align_ef(state, trainable)
+        freeze = derive(state)
+        state, step_fn = repartition(state, freeze)
 
         def _checkpoint_state(st):
             """Expand row-packed moments (and EF error buffers) to full
@@ -362,12 +351,12 @@ class Trainer:
             buffers in device memory."""
             with span("/repro/train/host_layout"):
                 save_opt = expand_moments_host(st.opt, st.params, tcfg,
-                                               trainable)
+                                               freeze.trainable)
                 if save_opt is not st.opt:
                     st = dataclasses.replace(st, opt=save_opt)
                 if st.ef_error is not None:
                     save_ef = expand_packed_tree_host(st.ef_error, st.params,
-                                                      trainable)
+                                                      freeze.trainable)
                     if save_ef is not st.ef_error:
                         st = dataclasses.replace(st, ef_error=save_ef)
             return st
@@ -378,22 +367,6 @@ class Trainer:
             with span("/repro/train/guard_snapshot", step=step):
                 return snapshot_to_host(st)
 
-        # Multiplicative LR backoff applied by the numerics guard: each
-        # rollback halves (by rollback_lr_backoff) the LR of the re-dispatched
-        # program.  Folded into the compiled step via a config replace, so the
-        # schedule stays a pure function of opt.count.
-        lr_scale = 1.0
-
-        def compile_step(frozen_set, plan_, rows_, rplan_):
-            run_tcfg = (tcfg if lr_scale == 1.0 else
-                        dataclasses.replace(tcfg, lr=tcfg.lr * lr_scale))
-            return jax.jit(
-                make_multi_step(cfg, run_tcfg, spec, frozen_set,
-                                backend=backend, plan=plan_, row_frozen=rows_,
-                                reduce_plan=rplan_),
-                donate_argnums=0)
-
-        step_fn = compile_step(static_frozen, plan, row_frozen, reduce_plan)
         eval_fn = jax.jit(make_eval_step(cfg, tcfg)) if val_batches else None
 
         start_step = steps_completed(state)
@@ -455,11 +428,11 @@ class Trainer:
         # Boundary snapshot for the numerics guard: the whole state copied to
         # host memory in the step's own (packed) layout, refreshed at
         # each sync boundary once every drained block verified finite; the
-        # layout it was packed to rides along.  Rollback = put it back and
-        # re-derive the freeze artifacts from its masks — the same pure
-        # functions a restart runs, so replay is bit-deterministic.
+        # plan it was packed to rides along.  Rollback = put it back and
+        # re-derive the freeze plan from its masks — the same pure function
+        # a restart runs, so replay is bit-deterministic.
         snapshot = guard_snapshot(state, start_step) if guard_on else None
-        snapshot_step, snapshot_trainable = start_step, trainable
+        snapshot_step, snapshot_freeze = start_step, freeze
         best_val, val_bad = float("inf"), 0
         # --- watchdog state (block-granular; see module docstring) ---
         ema_dt: Optional[float] = None
@@ -633,8 +606,8 @@ class Trainer:
                 pending = cur
                 if tripped is not None:
                     break
-                need_t1 = (tcfg.grades.enabled and tcfg.grades.static_repartition
-                           and s % aligned_repart == 0 and s < tcfg.steps)
+                need_t1 = (tcfg.grades.enabled and s % aligned_repart == 0
+                           and s < tcfg.steps)
                 val_crossings = (s // val_interval - prev_s // val_interval
                                  if tcfg.val_es and eval_fn is not None else 0)
                 need_val = val_crossings > 0
@@ -651,43 +624,22 @@ class Trainer:
                         if tier2:
                             stop = "all_frozen"
                             break
-                        # Refresh the static freeze artifacts at repartition
-                        # boundaries AND before a checkpoint: the saved moment
-                        # layout must equal the pure function of the masks
-                        # being saved, so a resume re-derives it exactly.
+                        # Refresh the freeze plan at repartition boundaries
+                        # AND before a checkpoint: the saved moment layout
+                        # must equal the pure function of the masks being
+                        # saved, so a resume re-derives it exactly.
                         # Evaluating the (quantized) pure function more often
                         # cannot add recompiles — only distinct values count.
-                        if (need_t1 or need_ckpt) and tcfg.grades.enabled \
-                                and tcfg.grades.static_repartition:
+                        if (need_t1 or need_ckpt) and tcfg.grades.enabled:
                             with span("/repro/train/freeze_masks",
                                       step=bstart):
-                                new_static, new_plan, new_rows, new_rplan = \
-                                    freeze_artifacts(
-                                        jax.device_get(state.grades.frozen))
-                            # row masks and the reduce plan are pure functions
-                            # of (static, plan, spec), so the two comparisons
-                            # below cover them too
-                            if new_static != static_frozen or new_plan != plan:
+                                new = derive(state)
+                            if new != freeze:
                                 with span("/repro/train/repartition",
                                           step=bstart):
-                                    old_trainable = trainable
-                                    (static_frozen, plan, row_frozen,
-                                     reduce_plan) = (new_static, new_plan,
-                                                     new_rows, new_rplan)
-                                    trainable = trainable_mask(
-                                        state.params, spec, static_frozen,
-                                        row_frozen)
-                                    new_opt = align_moments(
-                                        state.opt, state.params, tcfg,
-                                        trainable, old_trainable)
-                                    if new_opt is not state.opt:
-                                        state = dataclasses.replace(
-                                            state, opt=new_opt)
-                                    state = _align_ef(state, trainable,
-                                                      old_trainable)
-                                    step_fn = compile_step(
-                                        static_frozen, plan, row_frozen,
-                                        reduce_plan)
+                                    state, step_fn = repartition(
+                                        state, new, freeze.trainable)
+                                    freeze = new
                                 recompiles += 1
                                 compile_pending = True  # paid at next dispatch
                         if need_val:
@@ -727,7 +679,7 @@ class Trainer:
                             # Everything drained above verified finite — this
                             # state is a safe rollback target.
                             snapshot = guard_snapshot(state, bstart)
-                            snapshot_step, snapshot_trainable = s, trainable
+                            snapshot_step, snapshot_freeze = s, freeze
                         # Boundary work (eval forward passes, the checkpoint's
                         # device_get, a Tier-1 recompile) is host/aux time,
                         # not block compute: restart the completion-delta
@@ -759,25 +711,17 @@ class Trainer:
                        "rollback": float(rollbacks), "lr_scale": lr_scale}
                 history.append(row)
                 self._log(row)
-                # Restore the boundary snapshot and re-derive every static
-                # artifact from its masks (identical to a cold restart from a
+                # Restore the boundary snapshot and re-derive the freeze plan
+                # from its masks (identical to a cold restart from a
                 # checkpoint of that boundary), then recompile with the
                 # backed-off LR.  The snapshot is packed to the layout of its
                 # boundary, which the re-derived one equals unless masks
                 # froze since the last refresh (a val-only boundary).
                 with span("/repro/train/rollback", step=tripped[0]):
                     state = restore_snapshot(snapshot)
-                    static_frozen, plan, row_frozen, reduce_plan = \
-                        freeze_artifacts(jax.device_get(state.grades.frozen))
-                    trainable = trainable_mask(state.params, spec,
-                                               static_frozen, row_frozen)
-                    new_opt = align_moments(state.opt, state.params, tcfg,
-                                            trainable, snapshot_trainable)
-                    if new_opt is not state.opt:
-                        state = dataclasses.replace(state, opt=new_opt)
-                    state = _align_ef(state, trainable, snapshot_trainable)
-                    step_fn = compile_step(static_frozen, plan, row_frozen,
-                                           reduce_plan)
+                    freeze = derive(state)
+                    state, step_fn = repartition(state, freeze,
+                                                 snapshot_freeze.trainable)
                 recompiles += 1
                 dispatched_sizes = set()
                 compile_pending = False

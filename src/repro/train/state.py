@@ -41,8 +41,7 @@ jax.tree_util.register_dataclass(
     meta_fields=[])
 
 
-def init_train_state(key, cfg: ModelConfig, tcfg: TrainConfig,
-                     static_frozen=frozenset()) -> TrainState:
+def init_train_state(key, cfg: ModelConfig, tcfg: TrainConfig) -> TrainState:
     from repro.models import model
     k1, k2 = jax.random.split(key)
     base = model.init_params(k1, cfg)
@@ -54,7 +53,7 @@ def init_train_state(key, cfg: ModelConfig, tcfg: TrainConfig,
         params = base
         base_params = None
         spec = build_monitor_spec(params)
-    trainable = trainable_mask(params, spec, static_frozen)
+    trainable = trainable_mask(params, spec, frozenset())
     opt = init_opt_state(params, tcfg, trainable)
     grades = init_grades_state(params, spec, tcfg.grades)
     ef = None

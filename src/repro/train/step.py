@@ -1,8 +1,10 @@
 """train_step / eval_step / multi_step factories.
 
-``make_train_step(cfg, tcfg, spec, static_frozen=...)`` closes over everything
-static and returns a pure ``(state, batch) -> (state, metrics)`` suitable for
-``jax.jit`` (the launcher adds in/out shardings and donates the state).
+``make_train_step(cfg, tcfg, spec, freeze=...)`` closes over everything static
+— the :class:`~repro.core.partition.FreezePlan` the host derived at the last
+repartition boundary among it — and returns a pure ``(state, batch) -> (state,
+metrics)`` suitable for ``jax.jit`` (the launcher adds in/out shardings and
+donates the state).
 
 One step = microbatched grads (lax.scan accumulation) → optional int8-EF
 compression → GradES monitor update (Algorithm 1) → masked optimizer update.
@@ -18,7 +20,7 @@ bit-identical to a per-step run that stopped exactly there.
 from __future__ import annotations
 
 import dataclasses
-from typing import AbstractSet, Any, Dict, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +31,7 @@ from repro.config import ModelConfig, TrainConfig
 from repro.core.grades import (MonitorSpec, all_frozen, frozen_fraction,
                                get_path, grades_update, set_path)
 from repro.core.lora import merge_lora
-from repro.core.partition import static_freeze_tree, trainable_mask
+from repro.core.partition import FreezePlan, freeze_plan, static_freeze_tree
 from repro.distributed import (active_mesh, active_rules,
                                compress_with_feedback, explicit_reduce_axes,
                                n_compressible, param_partition_specs,
@@ -78,10 +80,9 @@ def _loss(params, base_params, batch, cfg: ModelConfig, tcfg: TrainConfig,
 
 
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
-                    static_frozen: AbstractSet[str] = frozenset(),
+                    freeze: Optional[FreezePlan] = None,
                     backend: Optional[KernelBackend] = None,
-                    param_specs=None, plan=None, row_frozen=None,
-                    reduce_plan=None):
+                    param_specs=None):
     """``backend`` (resolved from ``tcfg.kernels`` when None) selects the fused
     Pallas monitor+update pipeline or the jnp reference path, per stacked group
     (DESIGN.md §3).  It is static per compiled step — the Tier-1 re-jit in the
@@ -95,14 +96,12 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
     launcher uses for state shardings.  LoRA parameter trees carry no
     logical-axis table, so sharded LoRA runs keep the jnp path per leaf.
 
-    ``plan`` (a :class:`~repro.core.partition.SegmentPlan`) segments the layer
-    scan so per-layer frozen rows stop costing dW FLOPs, and ``row_frozen``
-    (the plan-quantized masks from ``partition.plan_row_masks`` — not the raw
-    device masks, which would churn the layout per freeze) packs their
-    optimizer moments to live rows only — both static per compiled step,
-    refreshed by the trainer's Tier-1 re-jit (DESIGN.md §2).
-
-    ``reduce_plan`` (a :class:`~repro.core.partition.ReducePlan`) drives the
+    ``freeze`` (a :class:`~repro.core.partition.FreezePlan`, None when
+    nothing is frozen) is static per compiled step and refreshed by the
+    trainer's re-jit (DESIGN.md §2): its whole-type set wraps those leaves in
+    ``stop_gradient``, its segment plan chains the layer scan so per-layer
+    frozen rows stop costing dW FLOPs, its ``trainable`` packs their
+    optimizer moments to live rows only, and its reduce plan drives the
     freeze-aware explicit data-parallel reduce (DESIGN.md §3): on an eligible
     pure-DP mesh (``distributed/reduce.py::explicit_reduce_axes``) gradients
     are computed inside a shard_map manual over the DP axes and psum'd
@@ -110,7 +109,6 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
     gradients are exactly zero, so the drop is bit-identical to the full-tree
     reduce while the bytes leave the compiled HLO.
     """
-    static_frozen = frozenset(static_frozen)
     backend = resolve_backend(tcfg.kernels) if backend is None else backend
     dp_mesh = active_mesh()
     dp_axes = explicit_reduce_axes(dp_mesh, tcfg)
@@ -130,6 +128,16 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
         rules = ShardingRules()
     _derived: Dict[str, Any] = {}
 
+    def freeze_for(params) -> FreezePlan:
+        """``freeze``, or the plan of nothing frozen (derived once)."""
+        if freeze is not None:
+            return freeze
+        if "freeze" not in _derived:
+            _derived["freeze"] = freeze_plan({}, params, spec, cfg.n_layers,
+                                             tcfg.segment_max, segments=False,
+                                             pack_rows=False)
+        return _derived["freeze"]
+
     def specs_for(params):
         if param_specs is not None:
             return param_specs
@@ -141,9 +149,12 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
         return _derived["specs"]
 
     def grads_of(params, base_params, batch):
+        fz = freeze_for(params)
+
         def f(p):
-            p = static_freeze_tree(p, spec, static_frozen)
-            return _loss(p, base_params, batch, cfg, tcfg, attn_args, plan)
+            p = static_freeze_tree(p, spec, fz.whole)
+            return _loss(p, base_params, batch, cfg, tcfg, attn_args,
+                         fz.segments)
         (loss, metrics), grads = jax.value_and_grad(f, has_aux=True)(params)
         return loss, metrics, grads
 
@@ -186,7 +197,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
             with suspend_mesh():
                 loss, metrics, grads = local_grads(params, base_params,
                                                    batch, mb_local)
-            grads = reduce_gradients(grads, dp_axes, reduce_plan)
+            grads = reduce_gradients(grads, dp_axes,
+                                     freeze_for(params).reduce)
             loss = jax.lax.pmean(loss, dp_axes)
             metrics = _combine(metrics, lambda m: jax.lax.pmean(m, dp_axes),
                                lambda m: jax.lax.psum(m, dp_axes))
@@ -232,7 +244,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
         if fault_target is not None and fault_gain is not None:
             grads = splice_fault(grads, fault_gain)
 
-        trainable = trainable_mask(params, spec, static_frozen, row_frozen)
+        trainable = freeze_for(params).trainable
         ef_error = state.ef_error
         if tcfg.grad_compression == "int8_ef" and ef_error is not None:
             fault_index = None
@@ -283,10 +295,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
 
 
 def make_multi_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
-                    static_frozen: AbstractSet[str] = frozenset(),
+                    freeze: Optional[FreezePlan] = None,
                     backend: Optional[KernelBackend] = None,
-                    param_specs=None, plan=None, row_frozen=None,
-                    reduce_plan=None):
+                    param_specs=None):
     """Sync-boundary step: ``(state, block) -> (state, metrics)`` where
     ``block`` is a stacked ``(K, B, ...)`` batch pytree and every metric comes
     back as a ``(K,)`` array (one bulk ``device_get`` per block, DESIGN.md §4).
@@ -305,14 +316,13 @@ def make_multi_step(cfg: ModelConfig, tcfg: TrainConfig, spec: MonitorSpec,
     Tier-1.5 layout (``optimizer.packed_row_counts``): the row-packed
     leaves, their live rows, and the in-place row writes they compile to.
     """
-    single = make_train_step(cfg, tcfg, spec, static_frozen, backend=backend,
-                             param_specs=param_specs, plan=plan,
-                             row_frozen=row_frozen, reduce_plan=reduce_plan)
+    single = make_train_step(cfg, tcfg, spec, freeze, backend=backend,
+                             param_specs=param_specs)
     tier2 = tcfg.grades.enabled and bool(spec.groups)
 
     def multi_step(state, block):
-        mark("/repro/train/packed_rows", **packed_row_counts(trainable_mask(
-            state.params, spec, static_frozen, row_frozen)))
+        mark("/repro/train/packed_rows", **packed_row_counts(
+            freeze.trainable if freeze is not None else None))
 
         def run(state, batch):
             new_state, m = single(state, batch)

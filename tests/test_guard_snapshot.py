@@ -36,8 +36,7 @@ CASES = {
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
-from repro.core.partition import (fully_frozen_types, plan_row_masks,
-                                  segment_plan, trainable_mask)
+from repro.core.partition import freeze_plan
 from repro.optim.optimizer import align_moments, align_packed_tree
 from repro.train.state import init_train_state
 
@@ -52,9 +51,8 @@ frozen = {k: np.array(v) for k, v in
 frozen["layers/wq"][:] = True
 for name in ("layers/w_gate", "layers/w_up", "layers/w_down"):
     frozen[name][0] = True
-plan = segment_plan(frozen, spec, cfg.n_layers, tcfg.segment_max)
-trainable = trainable_mask(state.params, spec, fully_frozen_types(frozen),
-                           plan_row_masks(plan, spec, frozen))
+trainable = freeze_plan(frozen, state.params, spec, cfg.n_layers,
+                        tcfg.segment_max).trainable
 state = dataclasses.replace(
     state, opt=align_moments(state.opt, state.params, tcfg, trainable),
     ef_error=align_packed_tree(state.ef_error, state.params, jnp.float32,

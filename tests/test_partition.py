@@ -1,13 +1,15 @@
 """Tier-1 / Tier-1.5 partition unit tests: per-layer signatures, the segment
 planner's grid quantization + coalescing + recompile bound, per-row trainable
-masks (incl. the MoE per-expert path), and the dW skip accounting."""
+masks (incl. the MoE per-expert path), the freeze plan that carries them, and
+the dW skip accounting."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.grades import build_monitor_spec
-from repro.core.partition import (SegmentPlan, fully_frozen_types,
+from repro.core.partition import (SegmentPlan, freeze_plan,
+                                  fully_frozen_types, gradient_reduce_plan,
                                   plan_row_masks, plan_signature,
                                   plan_skipped_params, segment_plan,
                                   trainable_mask)
@@ -173,3 +175,71 @@ def test_plan_skipped_params():
     plan = segment_plan(fh, spec, L, segment_max=4)
     pool = sum(params["layers"][k].size for k in ("wq", "w_up", "w_gate"))
     assert plan_skipped_params(plan, params["layers"], L) == pool // 2
+
+
+def _assert_same_tree(a, b):
+    la, ta = jax.tree.flatten(a)
+    lb, tb = jax.tree.flatten(b)
+    assert ta == tb
+    for x, y in zip(la, lb):
+        assert type(x) is type(y)
+        np.testing.assert_array_equal(x, y)
+
+
+def _gate_rows(rows):
+    gate = np.zeros((L, E), bool)
+    gate[rows] = True
+    return gate
+
+
+@pytest.mark.parametrize("masks_of, segments", [
+    # per-layer only: wq's lower half and two whole expert rows
+    (lambda: {"layers/wq": np.arange(L) < 4,
+              "layers/w_gate": _gate_rows(slice(0, 2))}, True),
+    # whole type plus per-layer (the ft-wave shape): wq everywhere, the MLP
+    # in the lower layers
+    (lambda: {"layers/wq": np.ones(L, bool),
+              "layers/w_up": np.arange(L) < 4,
+              "layers/w_gate": _gate_rows(slice(0, 4))}, True),
+    # a family whose forward consumes no segment plan
+    (lambda: {"layers/wq": np.ones(L, bool),
+              "layers/w_up": np.arange(L) < 4}, False),
+], ids=["per_layer", "whole_and_per_layer", "no_segment_plan"])
+def test_freeze_plan_matches_its_parts(masks_of, segments):
+    """``freeze_plan`` is exactly the four derivations, field by field, and
+    two plans that differ only in freezes the quantized grid ignores compare
+    (and hash) equal, with the same rows, reduce plan and moment layout."""
+    params = make_params()
+    spec = build_monitor_spec(params)
+    seg_max = 4     # cells of two layers
+    fh = masks(spec, **masks_of())
+    fz = freeze_plan(fh, params, spec, L, seg_max, segments=segments)
+    whole = fully_frozen_types(fh)
+    plan = segment_plan(fh, spec, L, seg_max) if segments else None
+    rows = plan_row_masks(plan, spec, fh)
+    assert fz.whole == whole
+    assert fz.segments == plan
+    assert (plan is not None and not plan.trivial) == segments
+    if rows is None:
+        assert fz.rows is None
+    else:
+        assert fz.rows.keys() == rows.keys()
+        for name in rows:
+            np.testing.assert_array_equal(fz.rows[name], rows[name])
+    assert fz.reduce == gradient_reduce_plan(spec, whole, plan, L)
+    _assert_same_tree(fz.trainable, trainable_mask(params, spec, whole, rows))
+    # freezes inside a cell the wavefront has not completed: w_up at layer 5
+    # alone (cell [4, 6)) and one expert of layer 6
+    fh2 = dict(fh, **{"layers/w_up": fh["layers/w_up"] | (np.arange(L) == 5)})
+    fh2["layers/w_gate"] = fh["layers/w_gate"].copy()
+    fh2["layers/w_gate"][6, 0] = True
+    fz2 = freeze_plan(fh2, params, spec, L, seg_max, segments=segments)
+    assert fz2 == fz and hash(fz2) == hash(fz)
+    assert fz2.reduce == fz.reduce
+    _assert_same_tree(fz2.rows, fz.rows)
+    _assert_same_tree(fz2.trainable, fz.trainable)
+    # a completed cell is not ignored
+    fh3 = dict(fh2)
+    fh3["layers/w_up"] = fh2["layers/w_up"] | (np.arange(L) == 4)
+    assert (freeze_plan(fh3, params, spec, L, seg_max, segments=segments)
+            != fz) == segments

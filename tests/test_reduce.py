@@ -24,8 +24,8 @@ from jax.sharding import PartitionSpec as P
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import _key_path, build_monitor_spec
-from repro.core.partition import (fully_frozen_types, gradient_reduce_plan,
-                                  reduce_live_elements, segment_plan)
+from repro.core.partition import (freeze_plan, gradient_reduce_plan,
+                                  reduce_live_elements)
 from repro.distributed import (compress_with_feedback, dequantize_int8,
                                explicit_reduce_axes, make_mesh,
                                n_compressible, quantize_int8,
@@ -148,16 +148,15 @@ def test_gradient_reduce_plan_drop_slice_and_purity():
     frozen = {n: np.zeros(L, bool) for n in spec.groups}
     frozen["layers/wq"][0] = True   # per-layer: plan slices the live rows
     frozen["layers/wk"][:] = True   # whole type: Tier-1 drop
-    static = fully_frozen_types(frozen)
-    plan = segment_plan(frozen, spec, L, tcfg.segment_max)
-    rp = gradient_reduce_plan(spec, static, plan, L)
+    fz = freeze_plan(frozen, state.params, spec, L, tcfg.segment_max)
+    rp = fz.reduce
     assert dict(rp.entries) == {("layers", "wk"): (),
                                 ("layers", "wq"): ((1, 2),)}
     assert not rp.trivial
     assert gradient_reduce_plan(spec, frozenset(), None, L).trivial
-    # pure in (static, plan): hashable/comparable, so the trainer's Tier-1
-    # recompile comparison covers it
-    rp2 = gradient_reduce_plan(spec, static, plan, L)
+    # pure in (whole, segments): hashable/comparable, so the freeze plan's
+    # comparison covers it
+    rp2 = gradient_reduce_plan(spec, fz.whole, fz.segments, L)
     assert rp == rp2 and hash(rp) == hash(rp2) and {rp: 1}[rp2] == 1
     # byte accounting: the dropped leaf and the frozen layer row leave the
     # reduce entirely
@@ -196,9 +195,7 @@ def test_reduce_gradients_plan_matches_full_on_unit_mesh():
     frozen = {n: np.zeros(L, bool) for n in spec.groups}
     frozen["layers/wq"][0] = True
     frozen["layers/wk"][:] = True
-    static = fully_frozen_types(frozen)
-    plan = segment_plan(frozen, spec, L, tcfg.segment_max)
-    rp = gradient_reduce_plan(spec, static, plan, L)
+    rp = freeze_plan(frozen, state.params, spec, L, tcfg.segment_max).reduce
 
     rng = np.random.default_rng(1)
     lookup = rp.lookup()
@@ -358,8 +355,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
-from repro.core.partition import (fully_frozen_types, gradient_reduce_plan,
-                                  segment_plan)
+import dataclasses
+from repro.core.partition import ReducePlan, freeze_plan
 from repro.data.pipeline import make_batches
 from repro.distributed.sharding import use_mesh, make_mesh, DEFAULT_RULES
 from repro.train.state import init_train_state
@@ -388,15 +385,13 @@ with use_mesh(mesh, DEFAULT_RULES):
     s_p = s_f = state
     bi = 0
     for stage in range(3):
-        frozen = masks(stage)
-        static = fully_frozen_types(frozen)
-        plan = segment_plan(frozen, spec, L, tcfg.segment_max)
-        rp = gradient_reduce_plan(spec, static, plan, L)
-        assert rp.trivial == (stage == 0), (stage, rp)
-        planned = jax.jit(make_train_step(cfg, tcfg, spec, static, plan=plan,
-                                          reduce_plan=rp))
-        full = jax.jit(make_train_step(cfg, tcfg, spec, static, plan=plan,
-                                       reduce_plan=None))
+        # packed rows would break the mesh's moment shardings
+        fz = freeze_plan(masks(stage), state.params, spec, L,
+                         tcfg.segment_max, pack_rows=False)
+        assert fz.reduce.trivial == (stage == 0), (stage, fz.reduce)
+        planned = jax.jit(make_train_step(cfg, tcfg, spec, fz))
+        full = jax.jit(make_train_step(
+            cfg, tcfg, spec, dataclasses.replace(fz, reduce=ReducePlan())))
         for _ in range(2):
             b = jax.device_put(batches[bi], NamedSharding(mesh, P("data")))
             bi += 1

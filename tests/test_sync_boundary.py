@@ -19,7 +19,7 @@ import pytest
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
-from repro.core.partition import SegmentPlan, segment_plan
+from repro.core.partition import SegmentPlan, freeze_plan, segment_plan
 from repro.data.pipeline import (PackedFileDataset, Prefetcher, make_batches,
                                  stack_batches)
 from repro.models import model
@@ -154,8 +154,11 @@ def test_segmented_step_bit_identical_to_monolithic():
     state_a = init_train_state(jax.random.PRNGKey(0), CFG, tcfg)
     state_b = init_train_state(jax.random.PRNGKey(0), CFG, tcfg)
     spec = build_monitor_spec(state_a.params)
+    none = freeze_plan({}, state_a.params, spec, L, tcfg.segment_max,
+                       segments=False, pack_rows=False)
     mono = jax.jit(make_train_step(CFG, tcfg, spec))
-    segd = jax.jit(make_train_step(CFG, tcfg, spec, plan=plan))
+    segd = jax.jit(make_train_step(CFG, tcfg, spec,
+                                   dataclasses.replace(none, segments=plan)))
     for b in make_batches(CFG, tcfg, steps=3):
         state_a, m_a = mono(state_a, b)
         state_b, m_b = segd(state_b, b)
@@ -230,9 +233,9 @@ def test_resume_matches_uninterrupted():
 
 def test_resume_across_segment_max_change():
     """Checkpoints carry the plan-independent moment layout: a run saved with
-    per-row packed moments under one segment_max restores under another (and
-    with the repartition tier disabled) — re-packed to the restoring run's
-    own plan instead of erroring on layout provenance."""
+    per-row packed moments under one segment_max restores under another —
+    re-packed to the restoring run's own plan instead of erroring on layout
+    provenance."""
     d = tempfile.mkdtemp()
     try:
         tcfg = _tcfg(steps=32, sync_interval=4, checkpoint_dir=d,
@@ -251,16 +254,6 @@ def test_resume_across_segment_max_change():
                           repartition_interval=8, log_every=16).train()
             assert r_b.steps_run == 16
             shutil.rmtree(os.path.join(d, "step_32"))  # re-crash for the next
-        # and with the static tier off entirely (no plan -> no packed rows:
-        # every moment leaf is a placeholder or full param-shaped)
-        off = dataclasses.replace(
-            tcfg, grades=dataclasses.replace(tcfg.grades,
-                                             static_repartition=False))
-        r_c = Trainer(CFG, off, repartition_interval=8, log_every=16).train()
-        assert r_c.steps_run == 16
-        jax.tree.map(lambda m, p: None if m.size == 1 else
-                     np.testing.assert_array_equal(m.shape, p.shape),
-                     r_c.state.opt.m, r_c.state.params)
     finally:
         shutil.rmtree(d)
 

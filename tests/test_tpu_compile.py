@@ -30,8 +30,7 @@ from jax.sharding import PartitionSpec as P
 import repro.configs as configs
 from repro.config import GradESConfig, TrainConfig
 from repro.core.grades import build_monitor_spec
-from repro.core.partition import (fully_frozen_types, plan_row_masks,
-                                  segment_plan, trainable_mask)
+from repro.core.partition import freeze_plan
 from repro.distributed import explicit_reduce_axes, make_mesh, use_mesh
 from repro.kernels import compiled_kernels, ops
 from repro.kernels.decode_attention import paged_decode_attention
@@ -236,10 +235,8 @@ def test_packed_rows_are_written_back_in_place(one_chip):
     for name in ("layers/w_gate", "layers/w_up", "layers/w_down"):
         frozen[name][:16] = True
     frozen["layers/wq"][8:16] = True
-    static = fully_frozen_types(frozen)
-    plan = segment_plan(frozen, spec, cfg.n_layers, tcfg.segment_max)
-    rows = plan_row_masks(plan, spec, frozen)
-    trainable = trainable_mask(params, spec, static, rows)
+    freeze = freeze_plan(frozen, params, spec, cfg.n_layers, tcfg.segment_max)
+    trainable = freeze.trainable
 
     def init(key):
         st = init_train_state(key, cfg, tcfg)
@@ -252,8 +249,8 @@ def test_packed_rows_are_written_back_in_place(one_chip):
     block = {k: jax.ShapeDtypeStruct((2, 4, 128), jnp.int32,
                                      sharding=one_chip)
              for k in ("tokens", "labels")}
-    step = make_multi_step(cfg, tcfg, spec, static, backend=KernelBackend(
-        "pallas", interpret=False), plan=plan, row_frozen=rows)
+    step = make_multi_step(cfg, tcfg, spec, freeze, backend=KernelBackend(
+        "pallas", interpret=False))
     marks = []
 
     def listen(event, _t0, _t1, **attrs):

@@ -55,8 +55,7 @@ def test_grades_all_frozen_terminates_early():
 @pytest.mark.slow
 def test_frozen_matrices_stop_moving():
     tcfg = _tcfg(steps=60, grades=GradESConfig(
-        enabled=True, tau=1e3, alpha=0.2, normalize=True, patience=1,
-        static_repartition=False))
+        enabled=True, tau=1e3, alpha=0.2, normalize=True, patience=1))
     tr = Trainer(CFG, tcfg, log_every=10)
     state = tr.init_state()
     spec = build_monitor_spec(state.params)
